@@ -45,6 +45,8 @@ def main() -> int:
                     help="comma-separated bench names")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     all_csv: list[str] = []
     failures = 0

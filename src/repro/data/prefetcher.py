@@ -67,20 +67,14 @@ def put_global_batch(batch, sharding=None, *, donate: bool = False,
     reused) — the prefetcher passes False for arena-backed batches.
     """
     if sharding is None:
-        try:
-            return jax.device_put(batch, donate=donate, may_alias=may_alias)
-        except TypeError:  # pragma: no cover - older jax signature
-            return jax.device_put(batch)
+        return jax.device_put(batch, donate=donate, may_alias=may_alias)
 
     def _put(x):
         x = np.asarray(x)
         if jax.process_count() > 1:  # pragma: no cover - multi-host only
             return jax.make_array_from_process_local_data(sharding, x)
-        try:
-            return jax.device_put(x, sharding, donate=donate,
-                                  may_alias=may_alias)
-        except TypeError:  # pragma: no cover - older jax signature
-            return jax.device_put(x, sharding)
+        return jax.device_put(x, sharding, donate=donate,
+                              may_alias=may_alias)
 
     return jax.tree_util.tree_map(_put, batch)
 

@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def ring_weight_matmul(x, w, mesh: Mesh, *, axis: str = "model"):
@@ -46,13 +45,12 @@ def ring_weight_matmul(x, w, mesh: Mesh, *, axis: str = "model"):
             wblk = jax.lax.ppermute(wblk, axis, perm)
             return out, wblk
 
-        out0 = jnp.zeros((x_local.shape[0], f), jnp.float32)
-        if hasattr(jax.lax, "pvary"):  # shard_map vma typing (jax >= 0.6)
-            out0 = jax.lax.pvary(out0, (axis,))
+        out0 = jax.lax.pvary(jnp.zeros((x_local.shape[0], f), jnp.float32),
+                             (axis,))
         out, _ = jax.lax.fori_loop(0, n, step, (out0, w_local))
         return out
 
-    return shard_map(
+    return jax.shard_map(
         body_fn, mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
         out_specs=P(axis, None),

@@ -194,16 +194,22 @@ def constrain(x, *logical_axes: Optional[str]):
     return jax.lax.with_sharding_constraint(x, NamedSharding(ctx.mesh, pspec))
 
 
-def param_shardings(specs_logical_axes, abstract, mesh: Mesh,
-                    rules: Dict[str, MeshAxes]):
-    """Sharding tree for a param pytree given its logical-axes tree."""
-    ctx = ShardingCtx(mesh, rules)
+def params_shardings(model, ctx: ShardingCtx):
+    """NamedSharding tree for a model's params from its logical-axes tree."""
     return jax.tree_util.tree_map(
         lambda axes, arr: ctx.named_sharding(axes, arr.shape),
-        specs_logical_axes, abstract,
+        model.logical_axes(), model.abstract_params(),
         is_leaf=lambda t: isinstance(t, tuple) and all(
             a is None or isinstance(a, str) for a in t),
     )
+
+
+def batch_shardings(specs: Dict, ctx: ShardingCtx):
+    """NamedSharding per batch field: leading dim on the batch axes, the
+    rest replicated."""
+    return {k: ctx.named_sharding(("batch",) + (None,) * (v.ndim - 1),
+                                  v.shape)
+            for k, v in specs.items()}
 
 
 def rules_for(kind: str, *, seq_parallel: bool = False,

@@ -12,6 +12,10 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
   skipped via ``pl.when`` (halves work for causal, much more for SWA).
 
 Validated in interpret mode against ``ref.mha`` (see tests/test_kernels.py).
+
+The backward pass is XLA's: the VJP of ``ref.mha_chunked`` (the same
+blockwise math), recomputed (``ref.oracle_vjp``), until a Pallas backward
+lands.
 """
 from __future__ import annotations
 
@@ -22,13 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params: name moved across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import ref
 
 NEG_INF = -1e30
 
@@ -99,19 +99,10 @@ def _pad_to(x, axis: int, mult: int):
     return jnp.pad(x, widths)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_k", "q_offset",
-                     "interpret", "scale"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    q_offset: int = 0, scale: Optional[float] = None,
-                    block_q: int = 256, block_k: int = 256,
-                    interpret: bool = False):
-    """q: (B,S,H,D); k, v: (B,T,K,D), H % K == 0.  Returns (B,S,H,D)."""
+def _flash_fwd(q, k, v, *, causal: bool, window: int, q_offset: int,
+               scale: float, block_q: int, block_k: int, interpret: bool):
     B, S, H, D = q.shape
     _, T, K, _ = k.shape
-    assert H % K == 0, (H, K)
-    scale = float(scale if scale is not None else D ** -0.5)
     block_q = min(block_q, max(S, 8))
     block_k = min(block_k, max(T, 8))
 
@@ -129,8 +120,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         q_offset=q_offset)
 
     params = {}
-    if _COMPILER_PARAMS is not None and not interpret:
-        params["compiler_params"] = _COMPILER_PARAMS(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
 
@@ -156,3 +147,37 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         **params,
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :S, :D], 1, 2)
+
+
+def _flash_oracle(q, k, v, *, causal: bool, window: int, q_offset: int,
+                  scale: float):
+    if q_offset == 0:
+        return ref.mha_chunked(q, k, v, causal=causal, window=window,
+                               scale=scale)
+    B, S = q.shape[:2]
+    q_pos = jnp.broadcast_to(q_offset + jnp.arange(S)[None, :], (B, S))
+    return ref.mha(q, k, v, causal=causal, window=window, q_pos=q_pos,
+                   scale=scale)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "window", "block_q", "block_k", "q_offset",
+                     "interpret", "scale"))
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    q_offset: int = 0, scale: Optional[float] = None,
+                    block_q: int = 256, block_k: int = 256,
+                    interpret: bool = False):
+    """q: (B,S,H,D); k, v: (B,T,K,D), H % K == 0.  Returns (B,S,H,D).
+
+    Differentiable; the backward is XLA's (VJP of ``ref.mha_chunked``)
+    until a Pallas backward lands."""
+    H, K, D = q.shape[2], k.shape[2], q.shape[3]
+    assert H % K == 0, (H, K)
+    scale = float(scale if scale is not None else D ** -0.5)
+    kernel = functools.partial(
+        _flash_fwd, causal=causal, window=window, q_offset=q_offset,
+        scale=scale, block_q=block_q, block_k=block_k, interpret=interpret)
+    oracle = functools.partial(_flash_oracle, causal=causal, window=window,
+                               q_offset=q_offset, scale=scale)
+    return ref.oracle_vjp(kernel, oracle)(q, k, v)

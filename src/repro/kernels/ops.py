@@ -9,15 +9,22 @@ Models call these wrappers, never the kernels directly:
 * ``REPRO_KERNEL_IMPL`` env var forces ``ref`` / ``pallas`` /
   ``pallas_interpret`` (the last runs the kernel bodies in Python on CPU —
   that is how the test suite validates the TPU kernels here).
+
+Under a mesh (``sharding_rules.use_rules``) the Pallas kernels run inside a
+``shard_map`` over every mesh axis not already manual: XLA cannot
+partition a Mosaic kernel itself.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.distributed.sharding_rules import current_ctx
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
@@ -31,6 +38,27 @@ def _impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+def _per_shard(kernel, args, batched, *, n_out: int = 1):
+    """``kernel(*args)``, or under a mesh the same call per shard: batched
+    args and every output split on their leading (batch) dim over the
+    batch axes that are still automatic, everything else replicated."""
+    ctx = current_ctx()
+    auto = () if ctx is None else tuple(
+        a for a in ctx.mesh.axis_names if a not in ctx.manual)
+    if not auto:
+        return kernel(*args)
+
+    def spec(x):
+        return ctx.partition_spec(("batch",) + (None,) * (x.ndim - 1),
+                                  x.shape)
+
+    out = spec(args[0])
+    in_specs = tuple(spec(x) if b else P() for x, b in zip(args, batched))
+    return jax.shard_map(kernel, in_specs=in_specs,
+                         out_specs=out if n_out == 1 else (out,) * n_out,
+                         axis_names=set(auto), check_vma=False)(*args)
+
+
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               q_pos=None, kv_pos=None, kv_valid=None, softcap: float = 0.0,
               q_offset: int = 0, scale: Optional[float] = None,
@@ -40,10 +68,11 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     ragged = q_pos is not None or kv_pos is not None or kv_valid is not None \
         or softcap > 0.0 or num_sink > 0
     if impl.startswith("pallas") and not ragged:
-        return _fa.flash_attention(
-            q, k, v, causal=causal, window=window, q_offset=q_offset,
-            scale=scale, block_q=block_q, block_k=block_k,
-            interpret=impl == "pallas_interpret")
+        kernel = functools.partial(
+            _fa.flash_attention, causal=causal, window=window,
+            q_offset=q_offset, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=impl == "pallas_interpret")
+        return _per_shard(kernel, (q, k, v), (True, True, True))
     if q_offset and q_pos is None:
         B, S = q.shape[:2]
         q_pos = jnp.broadcast_to(q_offset + jnp.arange(S)[None, :], (B, S))
@@ -62,8 +91,9 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
 def rmsnorm(x, scale, *, eps: float = 1e-6):
     impl = _impl()
     if impl.startswith("pallas"):
-        return _rn.rmsnorm(x, scale, eps=eps,
-                           interpret=impl == "pallas_interpret")
+        kernel = functools.partial(_rn.rmsnorm, eps=eps,
+                                   interpret=impl == "pallas_interpret")
+        return _per_shard(kernel, (x, scale), (True, False))
     return _ref.rmsnorm(x, scale, eps)
 
 
@@ -71,8 +101,10 @@ def rmsnorm_residual(x, residual, scale, *, eps: float = 1e-6):
     """Returns (normed, new_residual) for fused residual-add + norm."""
     impl = _impl()
     if impl.startswith("pallas"):
-        return _rn.rmsnorm_residual(x, residual, scale, eps=eps,
-                                    interpret=impl == "pallas_interpret")
+        kernel = functools.partial(_rn.rmsnorm_residual, eps=eps,
+                                   interpret=impl == "pallas_interpret")
+        return _per_shard(kernel, (x, residual, scale), (True, True, False),
+                          n_out=2)
     new_res = x + residual
     return _ref.rmsnorm(new_res, scale, eps), new_res
 
@@ -91,8 +123,10 @@ def ssd(x, dt, A, B, C, *, chunk: int = 256):
         C = jnp.pad(C, widths)
         dt = jnp.pad(dt, [(0, 0), (0, pad), (0, 0)])
     if impl.startswith("pallas"):
-        y = _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk,
-                          interpret=impl == "pallas_interpret")
+        kernel = functools.partial(_ssd.ssd_scan, chunk=chunk,
+                                   interpret=impl == "pallas_interpret")
+        y = _per_shard(kernel, (x, dt, A, B, C),
+                       (True, True, False, True, True))
     else:
         y, _ = _ref.ssd_chunked(x, dt, A, B, C, chunk=chunk)
     return y[:, :s] if pad else y

@@ -14,6 +14,29 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def oracle_vjp(kernel, oracle):
+    """``kernel`` forward, ``oracle``'s VJP (recomputed) as the backward.
+
+    The Pallas kernels have no backward of their own; this makes them
+    differentiable.  Both callables take the same differentiable positional
+    arrays (non-differentiable options are bound beforehand) and return
+    the same structure; the residuals are just the inputs."""
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        out, vjp = jax.vjp(oracle, *args)
+        g = jax.tree_util.tree_map(lambda c, o: c.astype(o.dtype), g, out)
+        return vjp(g)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 # --------------------------------------------------------------------------
 # attention
 # --------------------------------------------------------------------------

@@ -6,8 +6,9 @@ hypothesis loop — see EXPERIMENTS.md §Perf).
         --shape train_4k --mesh single [--top 25]
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if __name__ == "__main__":   # a script run only: importing sets nothing
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse      # noqa: E402
 import collections   # noqa: E402

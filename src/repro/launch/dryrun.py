@@ -1,8 +1,8 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-MUST set XLA_FLAGS before any other import (jax locks the device count on
-first init) — hence the first two lines.  Do NOT import this module from
-tests; run it as a script:
+Run as a script, it sets XLA_FLAGS (512 host devices) and JAX_PLATFORMS=cpu
+before jax is imported (jax locks the device count on first init).
+Importing it sets neither:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch yi-34b --shape train_4k --mesh single
     PYTHONPATH=src python -m repro.launch.dryrun --all            # every cell, resumable
@@ -11,23 +11,23 @@ Each cell writes artifacts/dryrun/<arch>__<shape>__<mesh>.json with
 memory_analysis, cost_analysis, collective bytes and roofline terms.
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if __name__ == "__main__":   # a script run only: importing sets nothing
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402
 import json              # noqa: E402
 import time              # noqa: E402
 import traceback         # noqa: E402
-from typing import Dict, Optional, Tuple  # noqa: E402
 
 import jax               # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import (SHAPES, ModelConfig, ShapeConfig,  # noqa: E402
                                 applicable_shapes, get_config, list_configs)
-from repro.distributed.sharding_rules import (ShardingCtx, rules_for,  # noqa: E402
-                                              use_rules)
+from repro.distributed.sharding_rules import (  # noqa: E402
+    ShardingCtx, batch_shardings, params_shardings, rules_for, use_rules)
 from repro.launch.mesh import make_production_mesh, mesh_chips  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.models.module import logical_axes  # noqa: E402
@@ -89,27 +89,6 @@ def choose_kv_dtype(model, cfg: ModelConfig, shape: ShapeConfig, chips: int):
 # ---------------------------------------------------------------------------
 # sharding trees
 # ---------------------------------------------------------------------------
-def params_shardings(model, ctx: ShardingCtx):
-    axes = model.logical_axes()
-    abstract = model.abstract_params()
-    return jax.tree_util.tree_map(
-        lambda ax, arr: ctx.named_sharding(ax, arr.shape), axes, abstract,
-        is_leaf=lambda x: isinstance(x, tuple) and all(
-            a is None or isinstance(a, str) for a in x))
-
-
-def batch_shardings(specs: Dict, ctx: ShardingCtx):
-    def shard_for(name, arr):
-        if arr.ndim == 1:
-            axes = ("batch",)
-        elif arr.ndim == 2:
-            axes = ("batch", None)
-        else:
-            axes = ("batch",) + (None,) * (arr.ndim - 1)
-        return ctx.named_sharding(axes, arr.shape)
-    return {k: shard_for(k, v) for k, v in specs.items()}
-
-
 CACHE_AXES = {
     "k": ("layers", "batch", "kv_seq", None, None),
     "v": ("layers", "batch", "kv_seq", None, None),

@@ -8,11 +8,12 @@ from __future__ import annotations
 
 import argparse
 import json
+from typing import Optional, Sequence
 
 import numpy as np
 
 
-def main() -> int:
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -22,8 +23,13 @@ def main() -> int:
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def build_frontend(args):
+    """Model with seeded random weights, ``ServeEngine`` and
+    ``BatchingFrontend`` from parsed launcher arguments.  Returns
+    (config, frontend)."""
     import jax
 
     from repro.configs import get_config, reduced
@@ -38,15 +44,30 @@ def main() -> int:
     engine = ServeEngine(model, params, max_batch=args.max_batch,
                          max_len=args.prompt_len + args.max_new + 8,
                          temperature=args.temperature)
-    frontend = BatchingFrontend(engine)
+    return cfg, BatchingFrontend(engine)
 
+
+def serve_requests(args, cfg, frontend):
+    """Submit ``args.requests`` seeded prompts; returns each answer's
+    generated token ids, in submission order."""
     rng = np.random.default_rng(args.seed)
     reqs = []
     for _ in range(args.requests):
         prompt = rng.integers(0, cfg.vocab_size, (args.prompt_len,))
         reqs.append(frontend.submit(prompt.astype(np.int32), args.max_new))
-    outs = [r.result.get(timeout=600) for r in reqs]
-    frontend.shutdown()
+    return [r.result.get(timeout=600) for r in reqs]
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = make_parser().parse_args(argv)
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    cfg, frontend = build_frontend(args)
+    try:
+        outs = serve_requests(args, cfg, frontend)
+    finally:
+        frontend.shutdown()
     print(json.dumps({
         "requests": len(outs),
         "batches_served": frontend.batches_served,

@@ -35,6 +35,7 @@ from repro.core.dpt import DPTConfig
 from repro.core.evaluators import LoaderEvaluator
 from repro.data.loader import DataLoader, LoaderParams
 from repro.distributed.fault_tolerance import StragglerDetector
+from repro.distributed.sharding_rules import current_ctx, params_shardings
 from repro.train.train_step import (TrainState, TrainStepConfig,
                                     init_train_state, make_train_step)
 from repro.tuning import (OnlineTuner, OnlineTunerConfig, adaptive_budget,
@@ -56,6 +57,9 @@ class TrainerConfig:
     # worker rung — see tuning.base.adaptive_budget)
     autotune_budget_batches: Optional[int] = None
     autotune_max_prefetch: int = 4
+    # largest worker count the startup grid tries (None: os.cpu_count(),
+    # which on a VM can count the physical host's cores, not the guest's)
+    autotune_num_cpu_cores: Optional[int] = None
     # candidate sampler locality_chunk values for the startup grid
     # (DESIGN.md §5).  None keeps the search on the paper's two axes;
     # include 0 in the tuple so fully-random order stays a candidate —
@@ -128,7 +132,7 @@ class Trainer:
         self.checkpointer = Checkpointer(cfg.checkpoint_dir) \
             if cfg.checkpoint_dir else None
         self.straggler = StragglerDetector()
-        self.step_fn = jax.jit(make_train_step(model, cfg.step_config))
+        self.step_fn = self._jit_step()
         self.state: Optional[TrainState] = None
         self.start_step = 0
         # reference batch for the linear-scaling LR hook: the geometry the
@@ -137,6 +141,16 @@ class Trainer:
         self.online_tuner: Optional[OnlineTuner] = None
         self.locality_controller = None
         self.history: List[Dict[str, Any]] = []
+        # the startup search's result (None when a cached pick was reused
+        # or autotune is off)
+        self.tune_result = None
+
+    def _jit_step(self):
+        """The jitted train step.  The state is donated: the step's output
+        state reuses its buffers, so params and optimizer moments are held
+        once, not twice."""
+        return jax.jit(make_train_step(self.model, self.cfg.step_config),
+                       donate_argnums=(0,))
 
     def connect_fleet(self, transport, *, join: bool = False,
                       coord: str = "coord", link_config=None,
@@ -215,6 +229,7 @@ class Trainer:
             return params
         ev = LoaderEvaluator(self.loader, to_device=True)
         search_cfg = DPTConfig(max_prefetch=self.cfg.autotune_max_prefetch,
+                               num_cpu_cores=self.cfg.autotune_num_cpu_cores,
                                locality_chunks=(tuple(locality_axis)
                                                 if locality_axis else None),
                                cache_budgets=(tuple(cache_axis)
@@ -240,6 +255,7 @@ class Trainer:
         result = tune(evaluator=ev, strategy=strategy,
                       config=search_cfg, **kwargs)
         cache.put(mfp, dfp, self.loader.global_batch, result)
+        self.tune_result = result
         rep = {"num_workers": result.nworker,
                "prefetch_factor": result.nprefetch}
         if locality_axis:
@@ -272,6 +288,7 @@ class Trainer:
                 cooldown_steps=self.cfg.retune_cooldown_steps,
                 retune_budget_batches=self.cfg.autotune_budget_batches,
                 max_prefetch=self.cfg.autotune_max_prefetch,
+                num_cpu_cores=self.cfg.autotune_num_cpu_cores,
                 locality_chunks=(tuple(chunks) if chunks else None),
                 cache_budgets=(tuple(budgets) if budgets else None),
                 slow_lanes=(tuple(lanes) if lanes else None),
@@ -299,16 +316,34 @@ class Trainer:
                                           on_propose=on_propose)
 
     # ---- checkpoint/restart ---------------------------------------------------
+    def _place_state(self, state: TrainState) -> TrainState:
+        """Under a mesh (the Trainer built and run inside
+        ``sharding_rules.use_rules``), lay params and moments out by the
+        sharding rules; off-mesh the state stays where it was made."""
+        ctx = current_ctx()
+        if ctx is None:
+            return state
+        p_sh = params_shardings(self.model, ctx)
+
+        def put(tree):
+            return None if tree is None else jax.device_put(tree, p_sh)
+
+        opt = state.opt._replace(
+            step=jax.device_put(state.opt.step, ctx.named_sharding(())),
+            mu=put(state.opt.mu), nu=put(state.opt.nu))
+        return TrainState(put(state.params), opt, put(state.err))
+
     def _maybe_restore(self) -> None:
         if self.checkpointer is None or self.checkpointer.latest_step() is None:
-            self.state = init_train_state(
+            self.state = self._place_state(init_train_state(
                 self.model, jax.random.PRNGKey(self.cfg.seed),
-                self.cfg.step_config)
+                self.cfg.step_config))
             return
         template = init_train_state(
             self.model, jax.random.PRNGKey(self.cfg.seed),
             self.cfg.step_config)
-        self.state, aux = self.checkpointer.restore(template)
+        state, aux = self.checkpointer.restore(template)
+        self.state = self._place_state(state)
         self.start_step = int(aux["step"])
         if "loader" in aux:
             self.loader.load_state_dict(aux["loader"])
@@ -354,8 +389,7 @@ class Trainer:
         self.cfg.step_config = dataclasses.replace(
             self.cfg.step_config,
             optimizer=dataclasses.replace(opt, peak_lr=opt.peak_lr * scale))
-        self.step_fn = jax.jit(make_train_step(self.model,
-                                               self.cfg.step_config))
+        self.step_fn = self._jit_step()
         self.history.append({"event": "lr_rescale", "scale": scale,
                              "global_batch": gb,
                              "peak_lr": self.cfg.step_config.optimizer.peak_lr})

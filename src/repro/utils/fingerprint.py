@@ -4,7 +4,8 @@ on the same machine upon loading data sets that have similar characteristics").
 A dataset fingerprint captures the characteristics that drive loader behaviour
 (item size distribution, decode cost class, count); a machine fingerprint
 captures the host resources that bound the search space (cores, RAM, device
-count).  DPT's cache is keyed on both.
+count) and the accelerator behind them (platform, device kind).  DPT's
+cache is keyed on both.
 """
 from __future__ import annotations
 
@@ -49,16 +50,24 @@ def dataset_fingerprint(*, item_bytes: float, decode_cost: float,
 
 def machine_fingerprint(*, cpu_count: int | None = None,
                         device_count: int | None = None,
-                        host_ram_bytes: int | None = None) -> str:
+                        host_ram_bytes: int | None = None,
+                        platform_name: str | None = None,
+                        device_kind: str | None = None) -> str:
+    """Host resources plus the accelerator the loader delivers to: a pick
+    tuned against one backend (say the CPU) is never reused on another
+    (a TPU chip), whose device_put costs differ."""
+    if device_count is None or platform_name is None or device_kind is None:
+        import jax
+
+        dev = jax.local_devices()[0]
+        if device_count is None:
+            device_count = jax.local_device_count()
+        if platform_name is None:
+            platform_name = dev.platform
+        if device_kind is None:
+            device_kind = dev.device_kind
     if cpu_count is None:
         cpu_count = os.cpu_count() or 1
-    if device_count is None:
-        try:
-            import jax
-
-            device_count = jax.local_device_count()
-        except Exception:  # pragma: no cover - jax always present here
-            device_count = 1
     if host_ram_bytes is None:
         try:
             host_ram_bytes = os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
@@ -67,6 +76,8 @@ def machine_fingerprint(*, cpu_count: int | None = None,
     return _stable_hash({
         "cpu": cpu_count,
         "devices": device_count,
+        "platform": platform_name,
+        "device_kind": device_kind,
         "ram_gb": round(host_ram_bytes / 2**30),
         "machine": platform.machine(),
     })

@@ -273,6 +273,11 @@ def wire_fleet():
         f.close()
 
 
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: multi-device subprocess tests (minutes, not seconds)")
+
+
 # --------------------------------------------------------------------------
 # per-test duration accounting (CI budget gate, see check_durations.py)
 # --------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Distributed machinery: sharding rules, fault tolerance, elastic planning,
 collective matmul + multi-device equivalence (subprocess with 8 CPU devs)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -138,10 +139,11 @@ def _run_subprocess(code: str):
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
            "REPRO_COMPUTE_DTYPE": "float32",
-           "JAX_PLATFORMS": "cpu", "HOME": "/root"}
+           "JAX_PLATFORMS": "cpu", "HOME": os.environ.get("HOME", "/")}
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=600,
-                       cwd="/root/repo", env=env)
+                       cwd=os.path.dirname(os.path.dirname(__file__)),
+                       env=env)
     assert r.returncode == 0, r.stderr[-3000:]
     return r.stdout
 
@@ -151,7 +153,8 @@ def test_ring_weight_matmul_equals_dot():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
         from repro.distributed.collective_matmul import ring_weight_matmul
-        mesh = jax.make_mesh((4,), ('model',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ('model',))
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 32))
         w = jax.random.normal(jax.random.PRNGKey(1), (32, 64))
         with mesh:
@@ -172,6 +175,7 @@ def test_sharded_loss_equals_unsharded():
         from repro.configs import get_config, reduced
         from repro.models import build_model
         from repro.distributed.sharding_rules import use_rules, rules_for
+        from repro.launch.mesh import make_mesh
         for arch in ['qwen2-0.5b', 'granite-moe-3b-a800m', 'mamba2-780m',
                      'hymba-1.5b']:
             cfg = reduced(get_config(arch))
@@ -183,7 +187,7 @@ def test_sharded_loss_equals_unsharded():
             batch = {'tokens': toks, 'targets': toks,
                      'loss_mask': jnp.ones((8, 32), jnp.float32)}
             ref, _ = m.loss(params, batch, remat_policy='none')
-            mesh = jax.make_mesh((2, 4), ('data', 'model'))
+            mesh = make_mesh((2, 4), ('data', 'model'))
             with use_rules(mesh, rules_for('train')):
                 sh, _ = jax.jit(lambda p, b: m.loss(
                     p, b, remat_policy='none'))(params, batch)
@@ -199,9 +203,9 @@ def test_compressed_psum_in_shard_map():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.grad_compress import compressed_psum
-        mesh = jax.make_mesh((8,), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('data',))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         err0 = jnp.zeros((8, 64))
 
@@ -210,7 +214,7 @@ def test_compressed_psum_in_shard_map():
             return mean[None], new_err[None]
 
         with mesh:
-            mean, err = shard_map(body, mesh=mesh,
+            mean, err = jax.shard_map(body, mesh=mesh,
                                   in_specs=(P('data'), P('data')),
                                   out_specs=(P('data'), P('data')))(g, err0)
         true_mean = g.mean(0)

@@ -18,11 +18,12 @@ import sys
 sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs.base import get_config, reduced
-from repro.distributed.sharding_rules import rules_for, use_rules
+from repro.distributed.sharding_rules import (rules_for, use_rules,
+                                              params_shardings, batch_shardings)
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train.train_step import TrainState, TrainStepConfig, make_train_step
 from repro.train.optimizer import init_adamw
-from repro.launch.dryrun import params_shardings, batch_shardings
 
 def make_batch(cfg, B, S, seed=0):
     r = np.random.default_rng(seed)
@@ -41,11 +42,19 @@ def run_py(code: str, timeout=560):
     return r.stdout
 
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "granite-moe-3b-a800m"])
-def test_manual_train_step_matches_single_device(arch):
+@pytest.mark.parametrize("arch,impl", [
+    pytest.param("qwen2-0.5b", "", id="qwen2-0.5b"),
+    pytest.param("granite-moe-3b-a800m", "", id="granite-moe-3b-a800m"),
+    # the Pallas kernels (interpreted) under the mesh: each runs per shard
+    # of the still-automatic 'model' axis (kernels/ops.py)
+    pytest.param("qwen2-0.5b", "pallas_interpret", id="qwen2-0.5b-pallas"),
+])
+def test_manual_train_step_matches_single_device(arch, impl):
     """One manual-DP train step on a (2,2,2) mesh == one single-device step
     (max param diff < 5e-3, driven by bf16 layout differences)."""
     out = run_py(f"""
+    if {impl!r}:
+        os.environ["REPRO_KERNEL_IMPL"] = {impl!r}
     cfg = reduced(get_config({arch!r}))
     model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
@@ -58,7 +67,7 @@ def test_manual_train_step_matches_single_device(arch):
     ref_state, ref_metrics = jax.jit(make_train_step(model, scfg))(state, batch)
     ref = jax.device_get(ref_state.params)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     import dataclasses
     scfg = dataclasses.replace(scfg, dp_manual=True)
     with use_rules(mesh, rules_for("train")) as ctx:
@@ -99,7 +108,7 @@ def test_serve_prefill_decode_match_single_device():
     ref_dec, _ = jax.jit(model.decode_step)(
         params, ref_cache, tokens[:, :1], jnp.full((B,), S, jnp.int32))
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     with use_rules(mesh, rules_for("prefill")) as ctx:
         wrapped = _serve_wrap(model, cfg, ctx, model.prefill)
         assert wrapped is not None
@@ -127,7 +136,7 @@ def test_cast_gather_partitioner_crash_workaround():
     run_py("""
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.distributed.dp_shard import gather_leaf
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     D, F, B = 8, 8, 8
     w = jax.device_put(jnp.arange(float(D * F)).reshape(D, F) / 10,
                        NamedSharding(mesh, P("data", None)))

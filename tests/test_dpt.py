@@ -230,3 +230,21 @@ def test_cache_reuses_similar_datasets(tmp_path):
     # persisted
     cache2 = DPTCache(str(tmp_path / "dpt.json"))
     assert cache2.get("machine", fp_a, 32) == (res.nworker, res.nprefetch)
+
+
+def test_machine_fingerprint_separates_device_kinds():
+    """A loader pick tuned on one accelerator is never reused on another:
+    the machine fingerprint includes the platform and device kind."""
+    from repro.utils.fingerprint import machine_fingerprint
+    host = dict(cpu_count=8, device_count=1, host_ram_bytes=64 << 30)
+    cpu = machine_fingerprint(platform_name="cpu", device_kind="cpu", **host)
+    tpu = machine_fingerprint(platform_name="tpu", device_kind="TPU v5 lite",
+                              **host)
+    assert cpu != tpu
+    assert cpu == machine_fingerprint(platform_name="cpu", device_kind="cpu",
+                                      **host)
+    # the defaults describe this process's own first device
+    import jax
+    dev = jax.local_devices()[0]
+    assert machine_fingerprint(**host) == machine_fingerprint(
+        platform_name=dev.platform, device_kind=dev.device_kind, **host)

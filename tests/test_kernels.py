@@ -195,3 +195,60 @@ def test_ssd_chunk_invariance_property(s, b):
                                atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(st8), np.asarray(st4),
                                atol=1e-4, rtol=1e-3)
+
+
+# --------------------------------------------------------------------------
+# gradients: kernel forward + oracle backward (ref.oracle_vjp)
+# --------------------------------------------------------------------------
+def _grad_case(name):
+    if name == "flash":
+        args = (_rand(0, (2, 32, 4, 16), jnp.float32),
+                _rand(1, (2, 32, 2, 16), jnp.float32),
+                _rand(2, (2, 32, 2, 16), jnp.float32))
+        return (args,
+                lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                block_q=16, block_k=16,
+                                                interpret=True),
+                lambda q, k, v: ref.mha(q, k, v, causal=True))
+    if name == "rmsnorm":
+        args = (_rand(0, (3, 5, 40), jnp.float32),
+                _rand(1, (40,), jnp.float32))
+        return (args, lambda x, s: rmsnorm_kernel(x, s, interpret=True),
+                lambda x, s: ref.rmsnorm(x, s))
+    if name == "rmsnorm_residual":
+        args = (_rand(0, (3, 5, 40), jnp.float32),
+                _rand(1, (3, 5, 40), jnp.float32),
+                _rand(2, (40,), jnp.float32))
+        return (args,
+                lambda x, r, s: rmsnorm_residual(x, r, s, interpret=True),
+                lambda x, r, s: (ref.rmsnorm(x + r, s), x + r))
+    b, s, h, p, g, n = 1, 32, 2, 8, 1, 4
+    args = (_rand(0, (b, s, h, p), jnp.float32),
+            jax.nn.softplus(_rand(1, (b, s, h), jnp.float32)),
+            -jnp.exp(_rand(2, (h,), jnp.float32) * 0.5),
+            _rand(3, (b, s, g, n), jnp.float32),
+            _rand(4, (b, s, g, n), jnp.float32))
+    return (args,
+            lambda *a: ssd_scan(*a, chunk=8, interpret=True),
+            lambda *a: ref.ssd_naive(*a)[0])
+
+
+@pytest.mark.parametrize("name", ["flash", "rmsnorm", "rmsnorm_residual",
+                                  "ssd"])
+def test_kernel_grad_matches_oracle(name):
+    """Every kernel is differentiable, and its gradient is the oracle's."""
+    args, kernel, oracle = _grad_case(name)
+
+    def loss(fn):
+        def f(*a):
+            out = fn(*a)
+            outs = out if isinstance(out, tuple) else (out,)
+            return sum(jnp.sum(jnp.sin(o)) for o in outs)
+        return f
+
+    argnums = tuple(range(len(args)))
+    got = jax.grad(loss(kernel), argnums=argnums)(*args)
+    expect = jax.grad(loss(oracle), argnums=argnums)(*args)
+    for g, e in zip(got, expect):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                   atol=1e-4, rtol=1e-3)

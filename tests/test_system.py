@@ -16,6 +16,8 @@ from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import TrainStepConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_end_to_end_dpt_tuned_training(tmp_path):
     """The headline integration: loader tuned by DPT (real wall-clock
@@ -67,14 +69,16 @@ def test_serve_end_to_end():
 def test_launchers_run(tmp_path):
     """The CLI entry points work end to end (reduced configs)."""
     import subprocess, sys, json
+    # the launchers turn JAX's persistent compile cache on; the suite
+    # keeps it off
     env = dict(os.environ, PYTHONPATH="src", REPRO_COMPUTE_DTYPE="float32",
-               JAX_PLATFORMS="cpu")
+               JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="false")
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "mamba2-780m",
          "--reduced", "--steps", "6", "--global-batch", "4",
          "--seq-len", "32", "--no-autotune",
          "--checkpoint-dir", str(tmp_path / "ck")],
-        capture_output=True, text=True, timeout=600, cwd="/root/repo",
+        capture_output=True, text=True, timeout=600, cwd=REPO,
         env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
@@ -84,8 +88,51 @@ def test_launchers_run(tmp_path):
         [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen2-0.5b",
          "--reduced", "--requests", "4", "--prompt-len", "8",
          "--max-new", "4", "--max-batch", "2"],
-        capture_output=True, text=True, timeout=600, cwd="/root/repo",
+        capture_output=True, text=True, timeout=600, cwd=REPO,
         env=env)
     assert r2.returncode == 0, r2.stderr[-2000:]
     out2 = json.loads(r2.stdout.strip().splitlines()[-1])
     assert out2["requests"] == 4
+
+
+_CACHE_PROBE = """
+import json, os, jax, jax.numpy as jnp
+from repro.utils.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+where = enable_compile_cache()
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8)).block_until_ready()
+print(json.dumps({"where": where, "repo": REPO_CACHE_DIR,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "repo"])
+def test_compile_cache_location(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, where set, is the only cache; unset, the
+    cache is the checkout's fixed .jax_cache."""
+    import json, subprocess, sys
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("JAX_COMPILATION_CACHE")}
+    env.update(PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    cache = tmp_path / "cache"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    repo_cache = os.path.join(REPO, ".jax_cache")
+    before = sorted(os.listdir(repo_cache)) if os.path.isdir(repo_cache) \
+        else None
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, timeout=300, cwd=REPO,
+                       env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["repo"] == repo_cache
+    if env_dir:
+        assert out["where"] == out["config"] == str(cache)
+        assert os.listdir(cache), "nothing was cached"
+        after = sorted(os.listdir(repo_cache)) \
+            if os.path.isdir(repo_cache) else None
+        assert after == before
+    else:
+        assert out["where"] == out["config"] == repo_cache

@@ -1,0 +1,100 @@
+"""The main path's Pallas kernels compile for a TPU v5e chip, forward and
+backward, at the widths the chip smoke runs (qwen2-0.5b attention and
+norms; mamba2-780m's SSD scan).
+
+Nothing runs: the TPU compiler builds each program for a described (not
+attached) v5e:2x2 topology, and each test asserts that the compiled HLO
+calls the Pallas kernel (``tpu_custom_call``).  The topology is described
+inside a fixture, never while a module is imported, and the persistent
+compilation cache is off around the compiles (a chip-less process cannot
+read back what it would write).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rmsnorm import rmsnorm, rmsnorm_residual
+from repro.kernels.ssd_scan import ssd_scan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _grad_of_sum(fn, argnums):
+    """Value and gradient of sum(fn): the value keeps the forward kernel
+    live (the gradient alone needs only the backward)."""
+    def loss(*args):
+        out = fn(*args)
+        outs = out if isinstance(out, tuple) else (out,)
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
+    return jax.value_and_grad(loss, argnums=argnums)
+
+
+# qwen2-0.5b: 14 query heads, 2 kv heads of dim 64, d_model 896
+_QWEN_ATTN = [((4, 1024, 14, 64), jnp.bfloat16),
+              ((4, 1024, 2, 64), jnp.bfloat16),
+              ((4, 1024, 2, 64), jnp.bfloat16)]
+_QWEN_NORM = [((4, 1024, 896), jnp.bfloat16), ((896,), jnp.float32)]
+_QWEN_NORM_RES = [((4, 1024, 896), jnp.bfloat16),
+                  ((4, 1024, 896), jnp.bfloat16), ((896,), jnp.float32)]
+# mamba2-780m: 48 heads of dim 64, state 128, chunk 256
+_MAMBA_SSD = [((1, 1024, 48, 64), jnp.bfloat16), ((1, 1024, 48), jnp.float32),
+              ((48,), jnp.float32), ((1, 1024, 1, 128), jnp.bfloat16),
+              ((1, 1024, 1, 128), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_compiles_for_v5e(one_chip, grad):
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+    if grad:
+        fn = _grad_of_sum(fn, (0, 1, 2))
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, *_QWEN_ATTN)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_rmsnorm_compiles_for_v5e(one_chip, grad):
+    fn = lambda x, s: rmsnorm(x, s, eps=1e-6)  # noqa: E731
+    if grad:
+        fn = _grad_of_sum(fn, (0, 1))
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, *_QWEN_NORM)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_rmsnorm_residual_compiles_for_v5e(one_chip, grad):
+    fn = lambda x, r, s: rmsnorm_residual(x, r, s, eps=1e-6)  # noqa: E731
+    if grad:
+        fn = _grad_of_sum(fn, (0, 1, 2))
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, *_QWEN_NORM_RES)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_ssd_scan_compiles_for_v5e(one_chip, grad):
+    fn = lambda x, dt, a, b, c: ssd_scan(x, dt, a, b, c,  # noqa: E731
+                                         chunk=256)
+    if grad:
+        fn = _grad_of_sum(fn, (0, 1, 2, 3, 4))
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, *_MAMBA_SSD)
