@@ -13,6 +13,7 @@ from typing import Optional
 from repro.core.monitor import MemoryOverflow
 from repro.core.simulator import LoaderSimulator
 from repro.data.loader import DataLoader, LoaderParams, TransferStats
+from repro.utils.spans import span
 
 
 class LoaderEvaluator:
@@ -42,15 +43,15 @@ class LoaderEvaluator:
         self.loader.with_params(self.loader.params.replace(
             num_workers=nworker, prefetch_factor=nprefetch,
             device_prefetch=self.device_prefetch))
-        kw = {} if cache_budget_bytes is None \
-            else {"cache_budget_bytes": cache_budget_bytes}
-        if slow_lane_workers is not None:
-            kw["slow_lane_workers"] = slow_lane_workers
-        if global_batch is not None:
-            kw["global_batch"] = global_batch
-        return self.loader.measure_transfer_time(
-            num_batches, epoch=epoch, to_device=self.to_device,
-            locality_chunk=locality_chunk, **kw)
+        axes = {"locality_chunk": locality_chunk,
+                "cache_budget_bytes": cache_budget_bytes,
+                "slow_lane_workers": slow_lane_workers,
+                "global_batch": global_batch}
+        kw = {k: v for k, v in axes.items() if v is not None}
+        with span("tune.trial", workers=nworker, prefetch=nprefetch,
+                  axes=",".join(f"{k}={v}" for k, v in kw.items())):
+            return self.loader.measure_transfer_time(
+                num_batches, epoch=epoch, to_device=self.to_device, **kw)
 
 
 class SimulatorEvaluator:

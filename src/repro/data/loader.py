@@ -33,6 +33,7 @@ from repro.data.sampler import SamplerState, ShardedSampler
 from repro.data.storage import storage_io_counters
 from repro.data.worker_pool import (ProcessWorkerPool, ThreadWorkerPool,
                                     batch_nbytes)
+from repro.utils.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1103,31 +1104,32 @@ class DataLoader:
                 total_bytes += batch_nbytes(b)
                 yield b
 
-        start = time.perf_counter()
-        prev = start
-        deltas: List[float] = []
-        prefetcher = None
-        try:
-            it = _counted(iter(pool))
-            if to_device:
-                prefetcher = DevicePrefetcher(
-                    it, depth=self.params.device_prefetch,
-                    sharding=self.sharding,
-                    transfer_threads=self.params.transfer_threads,
-                    donate=self.params.donate_transfer,
-                    staging_buffers=self.params.staging_buffers)
-                it = iter(prefetcher)
-            for _batch in it:
-                n += 1
-                now = time.perf_counter()
-                deltas.append(now - prev)
-                prev = now
-        except MemoryOverflow:
-            pool.shutdown()
-            return TransferStats(float("inf"), n, total_bytes,
-                                 overflowed=True,
-                                 peak_loader_bytes=monitor.peak)
-        elapsed = time.perf_counter() - start
+        with span("tune.measure"):
+            start = time.perf_counter()
+            prev = start
+            deltas: List[float] = []
+            prefetcher = None
+            try:
+                it = _counted(iter(pool))
+                if to_device:
+                    prefetcher = DevicePrefetcher(
+                        it, depth=self.params.device_prefetch,
+                        sharding=self.sharding,
+                        transfer_threads=self.params.transfer_threads,
+                        donate=self.params.donate_transfer,
+                        staging_buffers=self.params.staging_buffers)
+                    it = iter(prefetcher)
+                for _batch in it:
+                    n += 1
+                    now = time.perf_counter()
+                    deltas.append(now - prev)
+                    prev = now
+            except MemoryOverflow:
+                pool.shutdown()
+                return TransferStats(float("inf"), n, total_bytes,
+                                     overflowed=True,
+                                     peak_loader_bytes=monitor.peak)
+            elapsed = time.perf_counter() - start
         stats = TransferStats(elapsed, n, total_bytes,
                               peak_loader_bytes=monitor.peak,
                               batch_seconds=deltas)

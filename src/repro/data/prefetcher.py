@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.data.arena import ArenaBatch
+from repro.utils.spans import span
 
 _SENTINEL = object()
 
@@ -277,6 +278,12 @@ class DevicePrefetcher:
                 self._thread.join(timeout=0.05)
 
     def _transfer(self, batch):
+        nbytes = sum(getattr(x, "nbytes", 0)
+                     for x in jax.tree_util.tree_leaves(batch))
+        with span("loader.h2d", bytes=nbytes):
+            return self._transfer_batch(batch)
+
+    def _transfer_batch(self, batch):
         # ArenaBatch is a dict subclass, which jax's pytree registry treats
         # as a leaf — hand device_put a plain dict over the same arrays, and
         # forbid buffer aliasing so the recycled slab can't mutate the
@@ -295,13 +302,17 @@ class DevicePrefetcher:
                 raise
             return self._transfer_staged(batch, staged, staging)
         try:
-            dev = put_global_batch(payload, self.sharding, donate=self.donate,
-                                   may_alias=False if arena_backed else None)
+            with span("loader.put"):
+                dev = put_global_batch(payload, self.sharding,
+                                       donate=self.donate,
+                                       may_alias=False if arena_backed
+                                       else None)
             if arena_backed:
                 # device_put is asynchronous: the host->device copy may
                 # still be reading the slab.  Block (in this transfer
                 # thread, not the consumer) until the copy lands.
-                jax.block_until_ready(dev)
+                with span("loader.ready"):
+                    jax.block_until_ready(dev)
                 dev = self._ensure_private(dev, payload)
             return dev
         finally:
@@ -314,14 +325,18 @@ class DevicePrefetcher:
         settled once (alias -> retire) instead of verified-and-re-put per
         batch."""
         try:
-            batch.copy_into(staged)
+            with span("loader.stage"):
+                batch.copy_into(staged)
         finally:
             batch.release()        # slab is free the moment the copy ends
         try:
-            dev = put_global_batch(staged, self.sharding, donate=self.donate)
+            with span("loader.put"):
+                dev = put_global_batch(staged, self.sharding,
+                                       donate=self.donate)
             # the (async) put may still be reading the staging buffer — and
             # on a zero-copying backend the result may *be* the buffer
-            jax.block_until_ready(dev)
+            with span("loader.ready"):
+                jax.block_until_ready(dev)
         except BaseException:
             pool.release(staged)   # unused after a failed put
             raise
@@ -345,8 +360,9 @@ class DevicePrefetcher:
         for k, d in dev.items():
             h = np.asarray(host[k])
             if _leaf_aliases(d, h):
-                d = put_global_batch(np.array(h), self.sharding,
-                                     donate=self.donate)
+                with span("loader.reput"):
+                    d = put_global_batch(np.array(h), self.sharding,
+                                         donate=self.donate)
             fixed[k] = d
         return fixed
 

@@ -67,6 +67,7 @@ import numpy as np
 
 from repro.core.monitor import MemoryMonitor, MemoryOverflow
 from repro.data.arena import ArenaBatch, SlabArena, maybe_release
+from repro.utils.spans import span
 
 _SENTINEL = object()
 _SKIPPED = object()      # a fault policy dropped the whole batch: the
@@ -355,7 +356,8 @@ class ThreadWorkerPool:
                     break
                 try:
                     t0 = time.perf_counter()
-                    batch, nbytes = self._collate(idx, slot)
+                    with span("loader.collate", seq=seq):
+                        batch, nbytes = self._collate(idx, slot)
                     dt = time.perf_counter() - t0
                 except BaseException:
                     if slot is not None:    # not yet wrapped: recycle it
@@ -404,7 +406,8 @@ class ThreadWorkerPool:
                         and self._stop.is_set():
                     return
                 t0 = time.perf_counter()
-                batch, _ = self._collate(idx, slot)
+                with span("loader.collate"):
+                    batch, _ = self._collate(idx, slot)
                 if batch is None:
                     if self.on_skip is not None:
                         self.on_skip()

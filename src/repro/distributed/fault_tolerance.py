@@ -4,11 +4,12 @@ and elastic re-mesh planning.
 On a real fleet these hooks sit next to the coordinator (GCS / etcd); here
 they are in-process with injectable clocks so the behaviour — detection
 thresholds, restart decisions, re-mesh math — is testable deterministically.
-The Trainer wires them in: per-step durations feed the StragglerDetector
-(which can trigger a DPT re-tune on the slow host — the paper's knobs are
-exactly what drifts when a host degrades), heartbeats feed the
-HeartbeatRegistry, and a detected failure produces an ElasticPlan that maps
-(surviving hosts, old mesh) -> (new mesh, resharded restore).
+The fleet coordinator (``tuning/fleet.py``) wires them in: each host's
+reported step times feed the StragglerDetector (which can trigger a DPT
+re-tune on the slow host — the paper's knobs are exactly what drifts when
+a host degrades), heartbeats feed the HeartbeatRegistry, and a detected
+failure produces an ElasticPlan that maps (surviving hosts, old mesh) ->
+(new mesh, resharded restore).
 """
 from __future__ import annotations
 

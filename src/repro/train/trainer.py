@@ -4,9 +4,9 @@ feature rather than an offline script.
 Startup:  restore latest checkpoint (step + sampler offset + loader params)
           -> DPT-tune the loader (or reuse the cached result for this
           machine/dataset fingerprint) -> jit the train step.
-Steady:   device-prefetched batches -> train step; per-step wall time feeds
-          the StragglerDetector; every ``checkpoint_every`` steps an async
-          checkpoint (params, opt state, sampler state, loader params).
+Steady:   device-prefetched batches -> train step; every
+          ``checkpoint_every`` steps an async checkpoint (params, opt
+          state, sampler state, loader params).
 Drift:    an OnlineTuner (repro.tuning.online) watches the per-step
           data-wait vs compute-time goodput signal; when the loader
           becomes the bottleneck it runs a bounded re-search and
@@ -34,13 +34,13 @@ from repro.core.cache import DPTCache
 from repro.core.dpt import DPTConfig
 from repro.core.evaluators import LoaderEvaluator
 from repro.data.loader import DataLoader, LoaderParams
-from repro.distributed.fault_tolerance import StragglerDetector
 from repro.distributed.sharding_rules import current_ctx, params_shardings
 from repro.train.train_step import (TrainState, TrainStepConfig,
                                     init_train_state, make_train_step)
 from repro.tuning import (OnlineTuner, OnlineTunerConfig, adaptive_budget,
                           tune)
 from repro.utils.fingerprint import machine_fingerprint
+from repro.utils.spans import span, step_span
 
 
 @dataclasses.dataclass
@@ -131,7 +131,6 @@ class Trainer:
         self.agent = agent
         self.checkpointer = Checkpointer(cfg.checkpoint_dir) \
             if cfg.checkpoint_dir else None
-        self.straggler = StragglerDetector()
         self.step_fn = self._jit_step()
         self.state: Optional[TrainState] = None
         self.start_step = 0
@@ -361,12 +360,14 @@ class Trainer:
 
     def _rebuild_stream(self, step: int):
         """(Re)create the batch iterator from the consumed position."""
-        self.loader.sampler.state = self._consumed_state(step) \
-            if hasattr(self, "_stream_base") else self.loader.sampler.state
-        import copy
-        self._stream_base = copy.deepcopy(self.loader.sampler.state)
-        self._stream_base_step = step
-        return iter(self.loader)
+        with span("train.stream_start", step=step):
+            self.loader.sampler.state = self._consumed_state(step) \
+                if hasattr(self, "_stream_base") \
+                else self.loader.sampler.state
+            import copy
+            self._stream_base = copy.deepcopy(self.loader.sampler.state)
+            self._stream_base_step = step
+            return iter(self.loader)
 
     def _save(self, step: int, block: bool = False) -> None:
         if self.checkpointer is None:
@@ -409,10 +410,12 @@ class Trainer:
     # ---- main loop -----------------------------------------------------------
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        self._maybe_restore()
+        with span("train.init_state"):
+            self._maybe_restore()
         self._apply_delivery_defaults()
         if cfg.autotune:
-            self.tune_loader()
+            with span("train.tune"):
+                self.tune_loader()
             if self.agent is None:
                 self.online_tuner = self._make_online_tuner()
         if cfg.adaptive_locality:
@@ -420,45 +423,53 @@ class Trainer:
 
         step = self.start_step
         batches = self._rebuild_stream(step)
+        # a restore may have moved the geometry; each step's hooks re-check
+        self._maybe_rescale_lr()
         t_wall = time.perf_counter()
         last_metrics: Dict[str, Any] = {}
         while step < cfg.total_steps:
-            self._maybe_rescale_lr()
-            t0 = time.perf_counter()
-            try:
-                batch = next(batches)
-            except StopIteration:
-                batches = self._rebuild_stream(step)
-                batch = next(batches)
-            t_data = time.perf_counter() - t0
-            self.state, metrics = self.step_fn(self.state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            self.straggler.record(self.host_name, dt)
-            step += 1
+            i = step
+            with step_span("train.step", i):
+                with span("train.data_wait", step=i):
+                    t0 = time.perf_counter()
+                    try:
+                        batch = next(batches)
+                    except StopIteration:
+                        batches = self._rebuild_stream(step)
+                        batch = next(batches)
+                    t_data = time.perf_counter() - t0
+                with span("train.dispatch", step=i):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                with span("train.sync", step=i):
+                    jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                step += 1
 
-            # loader-drift retune (paper §5: cloud environments drift).
-            # A triggered retune hot-swaps the live stream in place — no
-            # rebuild, no lost batches, sampler position preserved.  In
-            # fleet mode the same signal streams to the coordinator
-            # instead (which may push a uniform retune or a reshard back).
-            if self.agent is not None:
-                self.agent.observe(data_s=t_data, step_s=dt)
-            elif self.online_tuner is not None:
-                self.online_tuner.observe(data_s=t_data, step_s=dt)
-            if self.locality_controller is not None:
-                self.locality_controller.step()
-
-            if step % cfg.log_every == 0 or step == cfg.total_steps:
-                rec = {"step": step,
-                       "loss": float(metrics["loss"]),
-                       "grad_norm": float(metrics["grad_norm"]),
-                       "lr": float(metrics["lr"]),
-                       "step_s": dt, "data_s": t_data}
-                self.history.append(rec)
-                last_metrics = rec
-            if self.checkpointer and step % cfg.checkpoint_every == 0:
-                self._save(step)
+                with span("train.log", step=i):
+                    if step % cfg.log_every == 0 or step == cfg.total_steps:
+                        rec = {"step": step,
+                               "loss": float(metrics["loss"]),
+                               "grad_norm": float(metrics["grad_norm"]),
+                               "lr": float(metrics["lr"]),
+                               "step_s": dt, "data_s": t_data}
+                        self.history.append(rec)
+                        last_metrics = rec
+                with span("train.hooks", step=i):
+                    # loader-drift retune (paper §5: cloud environments
+                    # drift).  A triggered retune hot-swaps the live
+                    # stream in place — no rebuild, no lost batches,
+                    # sampler position preserved.  In fleet mode the same
+                    # signal streams to the coordinator instead (which may
+                    # push a uniform retune or a reshard back).
+                    if self.agent is not None:
+                        self.agent.observe(data_s=t_data, step_s=dt)
+                    elif self.online_tuner is not None:
+                        self.online_tuner.observe(data_s=t_data, step_s=dt)
+                    if self.locality_controller is not None:
+                        self.locality_controller.step()
+                    if self.checkpointer and step % cfg.checkpoint_every == 0:
+                        self._save(step)
+                    self._maybe_rescale_lr()
         self._save(cfg.total_steps, block=True)
         wall = time.perf_counter() - t_wall
         return {"final_step": step, "wall_s": wall, **last_metrics}

@@ -44,6 +44,7 @@ from repro.data.loader import DataLoader, LoaderParams
 from repro.tuning.base import (adaptive_budget, steady_samples, tune,
                                welch_wins)
 from repro.utils.fingerprint import machine_fingerprint
+from repro.utils.spans import span
 
 
 @dataclasses.dataclass
@@ -517,6 +518,10 @@ class OnlineTuner:
         Also the entry point for external drift signals (e.g. the serving
         frontend's batch-mix monitor).
         """
+        with span("tune.retune", reason=reason):
+            return self._retune(reason)
+
+    def _retune(self, reason: str) -> Optional[LoaderParams]:
         orig = self.loader.params
         t0 = time.perf_counter()
         result = self.executor.search()
