@@ -4,12 +4,29 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
 * The grid's innermost dimension is executed sequentially on a TPU core, so
   the online-softmax running state (m, l, acc) lives in VMEM scratch that
   persists across KV-block grid steps — no shared-memory/warp machinery.
-* Block shapes are MXU/VPU aligned: block_q x head_dim and block_k x head_dim
-  tiles with head_dim padded to a multiple of 128 by the wrapper.
+  The row statistics m and l are kept as (block_q, 1) columns, the layout
+  the row reductions produce and the (block_q, head_dim) accumulator
+  broadcasts from; a (block_q,) vector would be relaid from lanes to
+  sublanes on every step.
+* Block shapes are block_q x head_dim and block_k x head_dim at the
+  published head dim: a block's last dim equals the array's, so 64, 96 and
+  128 need no padding.  Only the sequence dims are padded to block
+  multiples.
+* The MXU gets the inputs' own dtype.  ``QK^T`` of bf16 q and k is exact in
+  the f32 accumulator.  For ``PV`` with a v narrower than f32, the f32
+  probabilities p go in as two parts in v's dtype, ``p_hi = bf16(p)`` and
+  ``p_lo = bf16(p - p_hi)``: one bf16 part alone keeps p to 2^-8 relative,
+  the pair to about 2^-16, for a second MXU pass.  f32 inputs go in as
+  they are, at the default matmul precision, which on a v5e is one bf16
+  pass: an f32 configuration's p (and its q, k, v) keep about 2^-8, less
+  than the bf16 path's hi/lo p.
 * GQA is native: the kv-head index map folds the query-head -> kv-head
   mapping, so grouped heads never materialize repeated K/V.
-* Causal + sliding-window masking is positional; fully-masked KV blocks are
-  skipped via ``pl.when`` (halves work for causal, much more for SWA).
+* Causal + sliding-window masking is positional.  Fully-masked KV blocks
+  are skipped via ``pl.when`` (halves work for causal, much more for SWA).
+  Every computed block builds the mask that its call needs (causal,
+  window, padded keys); a non-causal call without a window or padding
+  builds none.
 
 Validated in interpret mode against ``ref.mha`` (see tests/test_kernels.py).
 
@@ -31,12 +48,26 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import ref
 
 NEG_INF = -1e30
+_QK = (((1,), (1,)), ((), ()))
+_PV = (((1,), (0,)), ((), ()))
+
+
+def _pv(p, v):
+    """``p @ v`` in f32; for a v narrower than f32, p as a hi/lo pair."""
+    if v.dtype == jnp.float32:
+        return jax.lax.dot_general(p, v, _PV,
+                                   preferred_element_type=jnp.float32)
+    p_hi = p.astype(v.dtype)
+    p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+    return (jax.lax.dot_general(p_hi, v, _PV,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(p_lo, v, _PV,
+                                  preferred_element_type=jnp.float32))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             scale: float, causal: bool, window: int, block_q: int,
-            block_k: int, num_kv_blocks: int, q_len: int, kv_len: int,
-            q_offset: int):
+            block_k: int, num_kv_blocks: int, kv_len: int, q_offset: int):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -57,37 +88,39 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                     # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                     # (bk, d)
-        v = v_ref[0, 0].astype(jnp.float32)                     # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        s = jax.lax.dot_general(q, k, _QK,
                                 preferred_element_type=jnp.float32) * scale
-
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kv_pos < kv_len                                  # pad keys
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # kv_pos - q_pos = rel - off
+        rel, off = col - row, q_start - kv_start
+        keep = []
         if causal:
-            mask &= kv_pos <= q_pos
+            keep.append(rel <= off)
         if window > 0:
-            mask &= q_pos - kv_pos < window
-        s = jnp.where(mask, s, NEG_INF)
+            keep.append(rel > off - window)
+        if kv_len % block_k:
+            keep.append(col < kv_len - kv_start)                # pad keys
+        if keep:
+            # -inf, not NEG_INF: exp(-inf - m) is 0 for the finite m the
+            # running max starts from, so p needs no second mask
+            s = jnp.where(functools.reduce(jnp.logical_and, keep), s,
+                          -jnp.inf)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]                                     # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _pv(p, v)
         m_scr[...] = m_new
 
     @pl.when(ik == num_kv_blocks - 1)
     def _finalize():
         l = l_scr[...]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -106,18 +139,17 @@ def _flash_fwd(q, k, v, *, causal: bool, window: int, q_offset: int,
     block_q = min(block_q, max(S, 8))
     block_k = min(block_k, max(T, 8))
 
-    # (B,H,S,D) layout; pad seq dims to block multiples, head_dim to 128.
-    qt = _pad_to(_pad_to(jnp.moveaxis(q, 2, 1), 2, block_q), 3, 128)
-    kt = _pad_to(_pad_to(jnp.moveaxis(k, 2, 1), 2, block_k), 3, 128)
-    vt = _pad_to(_pad_to(jnp.moveaxis(v, 2, 1), 2, block_k), 3, 128)
-    Sp, Tp, Dp = qt.shape[2], kt.shape[2], qt.shape[3]
+    # (B,H,S,D) layout; pad seq dims to block multiples.
+    qt = _pad_to(jnp.moveaxis(q, 2, 1), 2, block_q)
+    kt = _pad_to(jnp.moveaxis(k, 2, 1), 2, block_k)
+    vt = _pad_to(jnp.moveaxis(v, 2, 1), 2, block_k)
+    Sp, Tp = qt.shape[2], kt.shape[2]
     nq, nk = Sp // block_q, Tp // block_k
     group = H // K
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, num_kv_blocks=nk, q_len=S, kv_len=T,
-        q_offset=q_offset)
+        block_k=block_k, num_kv_blocks=nk, kv_len=T, q_offset=q_offset)
 
     params = {}
     if not interpret:
@@ -129,24 +161,25 @@ def _flash_fwd(q, k, v, *, causal: bool, window: int, q_offset: int,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, Dp), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, Dp),
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, Dp),
+            pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, Dp),
+        out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sp, Dp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),   # running max m
-            pltpu.VMEM((block_q,), jnp.float32),   # running denom l
-            pltpu.VMEM((block_q, Dp), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
+            pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
         **params,
     )(qt, kt, vt)
-    return jnp.moveaxis(out[:, :, :S, :D], 1, 2)
+    return jnp.moveaxis(out[:, :, :S], 1, 2)
 
 
 def _flash_oracle(q, k, v, *, causal: bool, window: int, q_offset: int,
@@ -166,7 +199,7 @@ def _flash_oracle(q, k, v, *, causal: bool, window: int, q_offset: int,
                      "interpret", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, scale: Optional[float] = None,
-                    block_q: int = 256, block_k: int = 256,
+                    block_q: int = 512, block_k: int = 512,
                     interpret: bool = False):
     """q: (B,S,H,D); k, v: (B,T,K,D), H % K == 0.  Returns (B,S,H,D).
 

@@ -62,7 +62,7 @@ def _per_shard(kernel, args, batched, *, n_out: int = 1):
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               q_pos=None, kv_pos=None, kv_valid=None, softcap: float = 0.0,
               q_offset: int = 0, scale: Optional[float] = None,
-              num_sink: int = 0, block_q: int = 256, block_k: int = 256):
+              num_sink: int = 0, block_q: int = 512, block_k: int = 512):
     """Multi-head (GQA) attention.  q: (B,S,H,D); k, v: (B,T,K,D)."""
     impl = _impl()
     ragged = q_pos is not None or kv_pos is not None or kv_valid is not None \

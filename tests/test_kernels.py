@@ -29,20 +29,52 @@ def _rand(key, shape, dtype):
         (2, 64, 64, 4, 2, 32, True, 0),      # GQA causal
         (2, 48, 48, 6, 2, 16, False, 0),     # non-causal (encoder)
         (1, 64, 64, 4, 1, 16, True, 20),     # sliding window, MQA
-        (2, 40, 40, 4, 4, 24, True, 0),      # non-pow2 seq + head_dim pad
+        (2, 40, 40, 4, 4, 24, True, 0),      # non-pow2 seq and head_dim
         (1, 128, 128, 8, 8, 64, True, 48),   # bigger window
+        (1, 1024, 1024, 14, 2, 64, True, 0),  # qwen2-0.5b heads, full+edge
+        (2, 64, 64, 4, 2, 96, True, 0),      # head_dim 96 (phi-3-vision)
+        (1, 128, 128, 4, 2, 128, True, 40),  # head_dim 128 with a window
+        (2, 40, 40, 4, 2, 16, False, 0),     # non-causal, padded keys
+        (1, 24, 56, 4, 2, 16, True, 0),      # q_offset 32 (serve prefill)
+        (1, 24, 56, 4, 2, 16, True, 20),     # q_offset 32 with a window
     ])
 def test_flash_matches_oracle(B, S, T, H, K, D, causal, window, dtype):
     q = _rand(0, (B, S, H, D), dtype)
     k = _rand(1, (B, T, K, D), dtype)
     v = _rand(2, (B, T, K, D), dtype)
+    # long sequences run at 256 blocks, a 4 x 4 grid of causally skipped,
+    # interior and diagonal blocks; fewer queries than keys are the last
+    # S positions, at q_offset T - S
+    block = 256 if S >= 1024 else 16
+    q_offset = T - S
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=16, block_k=16, interpret=True)
-    expect = ref.mha(q, k, v, causal=causal, window=window)
+                          q_offset=q_offset, block_q=block, block_k=block,
+                          interpret=True)
+    q_pos = jnp.broadcast_to(q_offset + jnp.arange(S)[None, :], (B, S))
+    expect = ref.mha(q, k, v, causal=causal, window=window, q_pos=q_pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("window", [0, 100])
+def test_flash_bf16_within_one_ulp(window):
+    """bf16 operands go to the MXU as they are, with ``p`` split into a
+    bf16 high and low part for ``PV``: the output is the exact attention
+    of the bf16 inputs, rounded once to bf16."""
+    q = _rand(0, (1, 1024, 4, 64), jnp.bfloat16)
+    k = _rand(1, (1, 1024, 2, 64), jnp.bfloat16)
+    v = _rand(2, (1, 1024, 2, 64), jnp.bfloat16)
+    out = flash_attention(q, k, v, causal=True, window=window,
+                          interpret=True)
+    expect = ref.mha(q.astype(jnp.float32), k.astype(jnp.float32),
+                     v.astype(jnp.float32), causal=True, window=window)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect.astype(jnp.bfloat16),
+                                          np.float32),
+                               rtol=2 ** -7, atol=1e-3)
 
 
 def test_flash_block_shape_invariance():

@@ -9,7 +9,9 @@ inside a fixture, never while a module is imported, and the persistent
 compilation cache is off around the compiles (a chip-less process cannot
 read back what it would write).
 """
+import importlib.util
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -67,12 +69,50 @@ _MAMBA_SSD = [((1, 1024, 48, 64), jnp.bfloat16), ((1, 1024, 48), jnp.float32),
               ((1, 1024, 1, 128), jnp.bfloat16)]
 
 
+def _bench_hlo():
+    """The benchmark's reader of Pallas calls in compiled HLO."""
+    path = Path(__file__).resolve().parents[1] / "chipbench" / "hlo.py"
+    spec = importlib.util.spec_from_file_location("chipbench_hlo", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _flash_calls(text):
+    return [c for c in _bench_hlo().pallas_calls(text)
+            if c["kernel"] == "flash_attention"]
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_flash_attention_compiles_for_v5e(one_chip, grad):
     fn = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
     if grad:
         fn = _grad_of_sum(fn, (0, 1, 2))
-    assert "tpu_custom_call" in _compile_text(fn, one_chip, *_QWEN_ATTN)
+    text = _compile_text(fn, one_chip, *_QWEN_ATTN)
+    assert "tpu_custom_call" in text
+    if not grad:
+        # one forward call, read by the benchmark's roofline at the
+        # published head dim: q (B, H, S, D) and k (B, K, T, D), unpadded
+        (call,) = _flash_calls(text)
+        assert call["operands"][0] == {"dtype": "bf16",
+                                       "shape": (4, 14, 1024, 64)}
+        assert call["operands"][1] == {"dtype": "bf16",
+                                       "shape": (4, 2, 1024, 64)}
+
+
+# head dims 96 (phi-3-vision) and 128 with a sliding window (mixtral):
+# full-dim blocks at every registered width
+@pytest.mark.parametrize("shapes,window", [
+    ([((1, 2048, 32, 96), jnp.bfloat16)] * 3, 0),
+    ([((1, 4096, 48, 128), jnp.bfloat16),
+      ((1, 4096, 8, 128), jnp.bfloat16),
+      ((1, 4096, 8, 128), jnp.bfloat16)], 1024),
+], ids=["d96", "d128_window"])
+def test_flash_attention_head_dims_compile_for_v5e(one_chip, shapes, window):
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True,  # noqa: E731
+                                         window=window)
+    (call,) = _flash_calls(_compile_text(fn, one_chip, *shapes))
+    assert call["operands"][0]["shape"][-1] == shapes[0][0][-1]
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
