@@ -10,3 +10,4 @@ import repro.configs.mamba2_780m  # noqa: F401
 import repro.configs.phi_3_vision_4_2b  # noqa: F401
 import repro.configs.whisper_large_v3  # noqa: F401
 import repro.configs.hymba_1_5b  # noqa: F401
+import repro.configs.granite_4_0_h_micro  # noqa: F401

@@ -12,6 +12,9 @@ from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
 
+LAYER_KINDS = ("mamba", "attention")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -51,11 +54,40 @@ class ModelConfig:
     # --- vlm stub ---
     num_patches: int = 0
     patch_embed_dim: int = 0         # incoming (pre-projection) patch dim
+    # --- interleaved layer kinds (granite-4.0-h) ---
+    # layer i is a ``layer_pattern[i % len]`` layer: "mamba" (RMSNorm, SSD
+    # mixer, RMSNorm, MLP) or "attention" (the same with GQA attention).
+    # Empty: every layer is the family's one block.
+    layer_pattern: Tuple[str, ...] = ()
+    position_embedding: str = "rope"  # rope | nope
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # scales each branch before its add
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0      # logits are divided by it
     # --- misc ---
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
     source: str = ""                 # provenance note [source; tier]
+
+    def __post_init__(self):
+        # a configuration file hands the pattern over as a JSON list; the
+        # config is a static (hashed) argument of jitted functions
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f"{self.name}: unknown layer kinds "
+                             f"{sorted(unknown)}; known: {LAYER_KINDS}")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"{self.name}: position_embedding "
+                             f"{self.position_embedding!r} is not rope or nope")
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's kind, for a ``layer_pattern`` model."""
+        p = self.layer_pattern
+        return tuple(p[i % len(p)] for i in range(self.num_layers)) if p \
+            else ()
 
     @property
     def d_inner(self) -> int:
@@ -84,7 +116,8 @@ class ModelConfig:
 
     @property
     def has_decoder(self) -> bool:
-        return True  # every assigned arch decodes (whisper is enc-dec)
+        # whisper is enc-dec; a layer-pattern model trains only
+        return not self.layer_pattern
 
     def param_count(self) -> int:
         """Analytic parameter count N (total, incl. all experts)."""
@@ -93,6 +126,18 @@ class ModelConfig:
                                  self.num_layers)
         emb = v * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
+        attn = d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
+        if self.layer_pattern:
+            din, ns = self.d_inner, self.ssm_state_dim
+            ng, nh = self.ssm_num_groups, self.ssm_num_heads
+            # in_proj, conv weight and bias, dt_bias, A, D, gate-norm, out
+            mixer = d * (2 * din + 2 * ng * ns + nh) \
+                + (din + 2 * ng * ns) * (self.ssm_conv_width + 1) \
+                + 3 * nh + din + din * d
+            mlp_norms = 3 * d * f + 2 * d
+            kinds = self.layer_kinds
+            return int(emb + d + kinds.count("mamba") * (mixer + mlp_norms)
+                       + kinds.count("attention") * (attn + mlp_norms))
         if self.family == "ssm":
             din, ns = self.d_inner, self.ssm_state_dim
             ng, nh = self.ssm_num_groups, self.ssm_num_heads
@@ -100,7 +145,6 @@ class ModelConfig:
             per_layer = in_proj + (din + 2 * ng * ns) * self.ssm_conv_width \
                 + 2 * nh + din + din * d + d  # A,D, gate-norm, out_proj, norm
         else:
-            attn = d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
             if self.family == "moe":
                 mlp = self.num_experts * 3 * d * self.expert_d_ff + d * self.num_experts
             elif self.mlp_activation == "gelu":
@@ -188,6 +232,8 @@ def applicable_shapes(cfg: ModelConfig) -> List[ShapeConfig]:
     for s in SHAPES.values():
         if s.name == "long_500k" and not cfg.is_subquadratic:
             continue
+        if s.kind != "train" and not cfg.has_decoder:
+            continue
         out.append(s)
     return out
 
@@ -221,6 +267,12 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         kw.update(sliding_window=16)
     if cfg.global_attn_layers:
         kw.update(global_attn_layers=(0,))
+    if cfg.layer_pattern:
+        # one layer for each of the first three runs of one kind (mamba,
+        # attention, mamba): both kinds, and a kind's stack split by another
+        runs = [k for i, k in enumerate(cfg.layer_kinds)
+                if i == 0 or cfg.layer_kinds[i - 1] != k][:3]
+        kw.update(layer_pattern=tuple(runs), num_layers=len(runs))
     return replace(cfg, **kw)
 
 

@@ -214,11 +214,14 @@ def ssd_chunked(x, dt, A, B, C, *, chunk: int = 64, initial_state=None):
     cum = jnp.cumsum(da, axis=2)                           # inclusive cumsum
     total = cum[:, :, -1:, :]                              # (b,nc,1,h)
 
-    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i  (decay j->i)
+    # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i  (decay j->i).
+    # Masked before the exp: above the diagonal cum_i - cum_j is the decay
+    # run backwards, which overflows once a chunk's dt*A sums past 88, and
+    # exp's VJP would then carry 0 * inf = NaN through the masked entries.
     li = cum[:, :, :, None, :]                             # (b,nc,c,1,h)
     lj = cum[:, :, None, :, :]                             # (b,nc,1,c,h)
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    L = jnp.where(causal[None, None, :, :, None], jnp.exp(li - lj), 0.0)
+    L = jnp.exp(jnp.where(causal[None, None, :, :, None], li - lj, -jnp.inf))
 
     xdt = xf * dtf[..., None]
     scores = jnp.einsum("bzihn,bzjhn->bzijh", Ch, Bh) * L  # (b,nc,c,c,h)

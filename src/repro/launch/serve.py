@@ -39,6 +39,10 @@ def build_frontend(args):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    if not cfg.has_decoder:
+        raise SystemExit(f"{cfg.name}: serving a layer_pattern model is not "
+                         f"implemented (pattern {cfg.layer_pattern}); it "
+                         f"trains only")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     engine = ServeEngine(model, params, max_batch=args.max_batch,

@@ -192,8 +192,11 @@ def _project_qkv(p, cfg: ModelConfig, x, kv_x):
 
 def attention(p, cfg: ModelConfig, x, *, positions=None, causal=True,
               window: int = 0, num_sink: int = 0, kv_x=None, rope=True,
+              scale: Optional[float] = None,
               out_axes=("batch", "seq", "embed_act")):
     """Full-sequence attention (train / prefill / encoder / cross).
+
+    ``scale``: the softmax scale (None: ``1/sqrt(head_dim)``).
 
     ``out_axes``: logical sharding of the output — under manual sequence
     parallelism the residual stream is seq-sharded on the model axis, so the
@@ -211,7 +214,7 @@ def attention(p, cfg: ModelConfig, x, *, positions=None, causal=True,
     if plan is not None:
         q, k, v = _pad_attention_heads(q, k, v, cfg, plan)
     out = ops.attention(q, k, v, causal=causal, window=window,
-                        num_sink=num_sink)
+                        num_sink=num_sink, scale=scale)
     if plan is not None:
         out = _unpad_attention_heads(out, cfg, plan)
     else:

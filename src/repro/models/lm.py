@@ -78,6 +78,8 @@ class DecoderLM:
         Returns (x, positions, text_start)."""
         cfg = self.cfg
         x = ll.embed(params["embed"], cfg, batch["tokens"])
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         B = x.shape[0]
         prefix = 0
         if cfg.num_patches and "patch_embeds" in batch:
@@ -104,6 +106,9 @@ class DecoderLM:
         x, aux = stk.run_stack(params["layers"], cfg, x, positions=positions,
                                causal=True, remat_policy=remat_policy)
         x = ll.norm(params["final_norm"], x, cfg)
+        if cfg.logits_scaling != 1.0:
+            # logits / s == (x / s) @ E^T, at S x D instead of S x V
+            x = x / cfg.logits_scaling
         if prefix:
             x = x[:, prefix:]
         mask = batch.get("loss_mask")
@@ -117,8 +122,16 @@ class DecoderLM:
         return loss, metrics
 
     # ---- inference ---------------------------------------------------------
+    def _require_decoder(self):
+        if not self.cfg.has_decoder:
+            raise NotImplementedError(
+                f"{self.cfg.name}: decoding a layer_pattern model is not "
+                f"implemented (pattern {self.cfg.layer_pattern}); it trains "
+                f"only")
+
     def prefill(self, params, batch, cache):
         """Run the prompt, fill the cache, return last-position logits."""
+        self._require_decoder()
         cfg = self.cfg
         x, positions, prefix = self._compose_input(params, batch)
         B, S = x.shape[0], x.shape[1]
@@ -210,6 +223,7 @@ class DecoderLM:
 
     def init_cache(self, batch: int, max_len: int, abstract: bool = False,
                    kv_dtype=None):
+        self._require_decoder()
         cfg = self.cfg
         internal = max_len + self._prefix_len
         import jax.numpy as _jnp
@@ -219,6 +233,7 @@ class DecoderLM:
     def decode_step(self, params, cache, tokens, positions):
         """tokens: (B,1); positions: (B,) text positions (the model offsets
         by the meta/patch prefix internally).  Returns (logits, new_cache)."""
+        self._require_decoder()
         cfg = self.cfg
         x = ll.embed(params["embed"], cfg, tokens)
         x = constrain(x, "batch", "seq", "embed_act")

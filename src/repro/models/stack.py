@@ -4,10 +4,17 @@ One block definition covers dense / moe / ssm / hybrid / encdec / vlm; the
 layer stack is ``lax.scan`` over stacked params so compile time and HLO size
 are independent of depth (60-88 layer configs lower as one block).  Remat
 policy is applied to the scan body for training.
+
+A ``layer_pattern`` model (granite-4.0-h) interleaves layer kinds instead:
+each kind has its own stacked params and block, and each maximal run of one
+kind is a scan over its slice of that kind's stack.  Each mixer and MLP
+runs under a ``jax.named_scope`` (``mixer.mamba``, ``mixer.attention``,
+``mlp``), so the step's op names attribute every op to a layer kind.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, Dict, Optional
 
 import jax
@@ -24,6 +31,14 @@ from repro.models.module import spec, stack_specs
 # --------------------------------------------------------------------------
 # specs
 # --------------------------------------------------------------------------
+def kind_specs(cfg: ModelConfig, kind: str) -> Dict[str, Any]:
+    """One layer of a ``layer_pattern`` model: norm, mixer, norm, MLP."""
+    mixer = {"ssm": ssm_mod.ssm_specs(cfg)} if kind == "mamba" \
+        else {"attn": ll.attention_specs(cfg)}
+    return {"ln1": ll.norm_specs(cfg), **mixer, "ln2": ll.norm_specs(cfg),
+            "mlp": ll.mlp_specs(cfg)}
+
+
 def block_specs(cfg: ModelConfig, *, cross: bool = False) -> Dict[str, Any]:
     if cfg.family == "ssm":
         return {"ln1": ll.norm_specs(cfg), "ssm": ssm_mod.ssm_specs(cfg)}
@@ -45,6 +60,10 @@ def block_specs(cfg: ModelConfig, *, cross: bool = False) -> Dict[str, Any]:
 
 def stack_param_specs(cfg: ModelConfig, num_layers: Optional[int] = None,
                       cross: bool = False):
+    if cfg.layer_pattern:
+        kinds = cfg.layer_kinds
+        return {k: stack_specs(kind_specs(cfg, k), kinds.count(k))
+                for k in sorted(set(kinds))}
     n = num_layers if num_layers is not None else cfg.num_layers
     return stack_specs(block_specs(cfg, cross=cross), n)
 
@@ -116,19 +135,24 @@ def block(p, cfg: ModelConfig, x, *, positions, is_global, causal=True,
         h = constrain(h, *RES_AXES)         # all-gather for full-seq attn
     ssm_cache = None
     if cfg.family == "ssm":
-        if ssm_state_out:
-            y, ssm_cache = ssm_mod.ssm(p["ssm"], cfg, h, return_state=True)
-        else:
-            y = ssm_mod.ssm(p["ssm"], cfg, h)
+        with jax.named_scope("mixer.mamba"):
+            if ssm_state_out:
+                y, ssm_cache = ssm_mod.ssm(p["ssm"], cfg, h, return_state=True)
+            else:
+                y = ssm_mod.ssm(p["ssm"], cfg, h)
         x = x + y
         return (x, aux, ssm_cache) if ssm_state_out else (x, aux)
 
-    attn_y = _attn_branch(p, cfg, h, positions, is_global, causal, res_axes)
+    with jax.named_scope("mixer.attention"):
+        attn_y = _attn_branch(p, cfg, h, positions, is_global, causal,
+                              res_axes)
     if cfg.family == "hybrid":
-        if ssm_state_out:
-            ssm_y, ssm_cache = ssm_mod.ssm(p["ssm"], cfg, h, return_state=True)
-        else:
-            ssm_y = ssm_mod.ssm(p["ssm"], cfg, h)
+        with jax.named_scope("mixer.mamba"):
+            if ssm_state_out:
+                ssm_y, ssm_cache = ssm_mod.ssm(p["ssm"], cfg, h,
+                                               return_state=True)
+            else:
+                ssm_y = ssm_mod.ssm(p["ssm"], cfg, h)
         mixed = 0.5 * (ll.rmsnorm(p["mix_norm_attn"], attn_y, cfg.norm_eps)
                        + ll.rmsnorm(p["mix_norm_ssm"], ssm_y, cfg.norm_eps))
         x = x + mixed
@@ -146,13 +170,80 @@ def block(p, cfg: ModelConfig, x, *, positions, is_global, causal=True,
     h2 = ll.norm(p["ln2"], x, cfg)
     if sp:
         h2 = constrain(h2, *RES_AXES)
-    if cfg.family == "moe":
-        y, aux_moe = ll.moe(p["moe"], cfg, h2, out_axes=res_axes)
-        aux = aux + aux_moe
-    else:
-        y = ll.mlp(p["mlp"], cfg, h2, out_axes=res_axes)
+    with jax.named_scope("mlp"):
+        if cfg.family == "moe":
+            y, aux_moe = ll.moe(p["moe"], cfg, h2, out_axes=res_axes)
+            aux = aux + aux_moe
+        else:
+            y = ll.mlp(p["mlp"], cfg, h2, out_axes=res_axes)
     x = x + y
     return (x, aux, ssm_cache) if ssm_state_out else (x, aux)
+
+
+def kind_block(p, cfg: ModelConfig, kind: str, x, *, positions):
+    """One layer of a ``layer_pattern`` model: each branch (the mixer, then
+    the MLP) normed, scaled by ``residual_multiplier`` and added."""
+    m = cfg.residual_multiplier
+    h = ll.norm(p["ln1"], x, cfg)
+    with jax.named_scope(f"mixer.{kind}"):
+        if kind == "mamba":
+            y = ssm_mod.ssm(p["ssm"], cfg, h)
+        else:
+            y = ll.attention(p["attn"], cfg, h, positions=positions,
+                             causal=True,
+                             rope=cfg.position_embedding == "rope",
+                             scale=cfg.attention_multiplier or None)
+    x = x + y * m
+    h = ll.norm(p["ln2"], x, cfg)
+    with jax.named_scope("mlp"):
+        y = ll.mlp(p["mlp"], cfg, h)
+    return x + y * m
+
+
+def kind_runs(kinds):
+    """Maximal runs of one kind: (kind, its first layer's index in that
+    kind's stack, length)."""
+    runs, seen = [], {}
+    for kind, group in itertools.groupby(kinds):
+        n = len(list(group))
+        runs.append((kind, seen.get(kind, 0), n))
+        seen[kind] = seen.get(kind, 0) + n
+    return runs
+
+
+def _remat(body, policy: str):
+    if policy == "none":
+        return body
+    return jax.checkpoint(body, policy={
+        "full": None,
+        "dots": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+        "nothing": jax.checkpoint_policies.nothing_saveable,
+    }[policy], prevent_cse=False)
+
+
+def _run_pattern(params, cfg: ModelConfig, x, *, positions,
+                 remat_policy: str):
+    """The layers of a ``layer_pattern`` model in order: one scan per
+    maximal run of one kind, over that run's slice of the kind's stack."""
+    from repro.distributed.sharding_rules import current_ctx
+    ctx = current_ctx()
+    if ctx is not None and ctx.manual:
+        raise NotImplementedError(
+            f"{cfg.name}: a layer_pattern model has no manual-DP layer hook")
+    # A run's slice of a stack is a copy, and its gradient is concatenated
+    # back: both in the compute dtype for the weight matrices, which every
+    # layer casts to it anyway (the same values, half the bytes of float32).
+    stacks = {k: jax.tree_util.tree_map(
+        lambda a: ll.cast(a) if a.ndim > 2 else a, v)
+        for k, v in params.items()}
+    for kind, start, n in kind_runs(cfg.layer_kinds):
+        def body(xc, p_layer, kind=kind):
+            return kind_block(p_layer, cfg, kind, xc,
+                              positions=positions), None
+        run = jax.tree_util.tree_map(lambda a: a[start:start + n],
+                                     stacks[kind])
+        x, _ = jax.lax.scan(_remat(body, remat_policy), x, run)
+    return x
 
 
 def run_stack(params, cfg: ModelConfig, x, *, positions, causal=True,
@@ -167,9 +258,16 @@ def run_stack(params, cfg: ModelConfig, x, *, positions, causal=True,
     reduce-scatters the bf16 grads — ZeRO-3 with minimal explicit traffic.
 
     Returns (x, aux) or (x, aux, ssm_caches) when collect_ssm_state."""
-    from repro.distributed import dp_shard
     from repro.distributed.sharding_rules import current_ctx
-    from repro.models.module import logical_axes
+
+    if cfg.layer_pattern:
+        if collect_ssm_state or enc_out is not None or not causal:
+            raise NotImplementedError(
+                f"{cfg.name}: a layer_pattern model runs causal training "
+                f"only (pattern {cfg.layer_pattern})")
+        x = _run_pattern(params, cfg, x, positions=positions,
+                         remat_policy=remat_policy)
+        return x, jnp.zeros((), jnp.float32)
 
     n = num_layers if num_layers is not None else cfg.num_layers
     flags = jnp.asarray(_global_flags(cfg)[:n]) if cfg.global_attn_layers \
@@ -206,14 +304,7 @@ def run_stack(params, cfg: ModelConfig, x, *, positions, causal=True,
                           sp=sp)
         return (xc, aux + aux_l), None
 
-    if remat_policy != "none":
-        policy = {
-            "full": None,
-            "dots": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            "nothing": jax.checkpoint_policies.nothing_saveable,
-        }[remat_policy]
-        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-
+    body = _remat(body, remat_policy)
     (x, aux), ssm_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                         (params, flags))
     if collect_ssm_state:

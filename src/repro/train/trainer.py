@@ -410,7 +410,9 @@ class Trainer:
     # ---- main loop -----------------------------------------------------------
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        with span("train.init_state"):
+        kinds = self.model.cfg.layer_kinds
+        with span("train.init_state",
+                  **{f"layers_{k}": kinds.count(k) for k in set(kinds)}):
             self._maybe_restore()
         self._apply_delivery_defaults()
         if cfg.autotune:

@@ -210,6 +210,33 @@ def test_ssd_decode_step_matches_scan():
                                atol=1e-4, rtol=1e-3)
 
 
+def test_ssd_chunked_vjp_finite_at_chunk_256():
+    """At the published chunk of 256 a chunk's dt*A sums far past 88, where
+    exp overflows in float32: the chunked form's VJP stays finite and is
+    the sequential recurrence's."""
+    b, s, h, p, g, n = 1, 512, 2, 8, 1, 4
+    x = _rand(0, (b, s, h, p), jnp.float32)
+    dt = jax.nn.softplus(_rand(1, (b, s, h), jnp.float32))
+    A = -jnp.exp(_rand(2, (h,), jnp.float32) * 0.5)
+    B = _rand(3, (b, s, g, n), jnp.float32)
+    C = _rand(4, (b, s, g, n), jnp.float32)
+    per_chunk = -(dt * A).reshape(b, s // 256, 256, h).sum(2)
+    assert float(per_chunk.min()) > 88.0
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a)))
+
+    argnums = tuple(range(5))
+    got = jax.grad(loss(lambda *a: ref.ssd_chunked(*a, chunk=256)[0]),
+                   argnums=argnums)(x, dt, A, B, C)
+    expect = jax.grad(loss(lambda *a: ref.ssd_naive(*a)[0]),
+                      argnums=argnums)(x, dt, A, B, C)
+    for g_, e in zip(got, expect):
+        assert bool(jnp.all(jnp.isfinite(g_)))
+        np.testing.assert_allclose(np.asarray(g_), np.asarray(e),
+                                   atol=1e-4, rtol=1e-3)
+
+
 @settings(max_examples=15, deadline=None)
 @given(st.integers(8, 48), st.integers(1, 3))
 def test_ssd_chunk_invariance_property(s, b):

@@ -245,3 +245,31 @@ def test_trainer_run_spans_its_set_up_and_each_step(tmp_path):
     assert len(by["loader.h2d"]) >= steps
     assert by["loader.collate"]
     assert main not in {r.thread for r in by["loader.h2d"]}
+
+
+@pytest.mark.parametrize("arch, kinds", [
+    ("granite-4.0-h-micro", {"layers_mamba": 2, "layers_attention": 1}),
+    ("qwen2-0.5b", {}),
+])
+def test_init_state_counts_each_layer_kind(arch, kinds):
+    """``train.init_state`` carries a layer-pattern model's count of each
+    layer kind; a model of one block carries none."""
+    from repro.configs import get_config, reduced
+    from repro.models import build_model
+    from repro.train.optimizer import AdamWConfig
+    from repro.train.train_step import TrainStepConfig
+    from repro.train.trainer import Trainer, TrainerConfig
+
+    cfg = reduced(get_config(arch))
+    ds = token_dataset(16, 16, cfg.vocab_size, seed=0)
+    dl = DataLoader(ds, 2, params=LoaderParams(num_workers=1), seed=0)
+    tc = TrainerConfig(total_steps=1, autotune=False,
+                       step_config=TrainStepConfig(
+                           remat_policy="none",
+                           optimizer=AdamWConfig(total_steps=1)))
+    tr = Trainer(build_model(cfg), dl, tc)
+    with spans.recording() as rec:
+        tr.run()
+    tr.loader._live_stream.close()
+    (init,) = rec.named("train.init_state")
+    assert init.attrs == kinds
