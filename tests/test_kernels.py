@@ -175,6 +175,10 @@ def test_rmsnorm_scale_property(rows, d):
         (2, 64, 4, 16, 2, 8, 16),
         (2, 64, 4, 16, 4, 8, 32),     # groups == heads
         (1, 96, 6, 8, 2, 16, 24),     # non-pow2 chunk
+        (1, 64, 8, 8, 1, 4, 16),      # a block of 8 heads over 4 chunks
+        (1, 48, 10, 8, 1, 8, 16),     # 10 heads in one group
+        (2, 48, 12, 8, 2, 8, 16),     # 6 heads a group, two groups
+        (1, 512, 6, 8, 1, 8, 256),    # chunk 256: its masked quarter skipped
     ])
 def test_ssd_kernel_matches_naive(b, s, h, p, g, n, chunk):
     x = _rand(0, (b, s, h, p), jnp.float32)
@@ -189,6 +193,59 @@ def test_ssd_kernel_matches_naive(b, s, h, p, g, n, chunk):
                                atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(expect),
                                atol=1e-4, rtol=1e-3)
+
+
+def _ssd_inputs(b, s, h, p, g, n, dtype=jnp.float32):
+    return (_rand(0, (b, s, h, p), dtype),
+            jax.nn.softplus(_rand(1, (b, s, h), jnp.float32)),
+            -jnp.exp(_rand(2, (h,), jnp.float32) * 0.5),
+            _rand(3, (b, s, g, n), dtype),
+            _rand(4, (b, s, g, n), dtype))
+
+
+def test_ssd_bf16_within_one_ulp():
+    """bf16 x, B and C with f32 dt, as the model passes them: the output is
+    the recurrence of the bf16 inputs, rounded once to bf16."""
+    x, dt, A, B, C = _ssd_inputs(1, 128, 8, 16, 1, 32, jnp.bfloat16)
+    out = ssd_scan(x, dt, A, B, C, chunk=32, interpret=True)
+    expect, _ = ref.ssd_naive(x.astype(jnp.float32), dt, A,
+                              B.astype(jnp.float32), C.astype(jnp.float32))
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect.astype(jnp.bfloat16),
+                                          np.float32),
+                               rtol=2 ** -7, atol=1e-3)
+
+
+def test_ssd_kernel_head_blocks_within_group(monkeypatch):
+    """A budget that fits 2 heads splits each group of 6 into three head
+    blocks, which share their group's B and C."""
+    from repro.kernels import ssd_scan as mod
+    budget = mod._vmem_bytes(2, 8, 8, 16, 4)
+    monkeypatch.setattr(mod, "_VMEM_BUDGET", budget)
+    assert mod._head_block(12, 2, 8, 8, 16, 4) == 2
+    x, dt, A, B, C = _ssd_inputs(1, 48, 12, 8, 2, 8)
+    kern = mod.ssd_scan(x, dt, A, B, C, chunk=16, interpret=True)
+    expect, _ = ref.ssd_naive(x, dt, A, B, C)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(expect),
+                               atol=1e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("h,n", [(64, 128), (48, 128), (50, 16)],
+                         ids=["granite-4.0-h-micro", "mamba2-780m",
+                              "hymba-1.5b"])
+def test_ssd_head_block_fits_budget(h, n):
+    """At each configuration's heads and state (one group, head dim 64,
+    chunk 256, bf16), the head block divides the heads and is the largest
+    divisor within the VMEM budget."""
+    from repro.kernels import ssd_scan as mod
+    hb = mod._head_block(h, 1, 64, n, 256, 2)
+    assert h % hb == 0
+    assert mod._vmem_bytes(hb, 64, n, 256, 2) <= mod._VMEM_BUDGET
+    larger = [d for d in range(hb + 1, h + 1) if h % d == 0]
+    assert all(mod._vmem_bytes(d, 64, n, 256, 2) > mod._VMEM_BUDGET
+               for d in larger)
+    assert hb >= 8
 
 
 def test_ssd_decode_step_matches_scan():

@@ -67,15 +67,27 @@ _QWEN_NORM_RES = [((4, 1024, 896), jnp.bfloat16),
 _MAMBA_SSD = [((1, 1024, 48, 64), jnp.bfloat16), ((1, 1024, 48), jnp.float32),
               ((48,), jnp.float32), ((1, 1024, 1, 128), jnp.bfloat16),
               ((1, 1024, 1, 128), jnp.bfloat16)]
+# granite-4.0-h-micro's cell: 64 heads of dim 64, state 128, one group,
+# 4096 tokens in chunks of 256
+_GRANITE_SSD = [((1, 4096, 64, 64), jnp.bfloat16),
+                ((1, 4096, 64), jnp.float32), ((64,), jnp.float32),
+                ((1, 4096, 1, 128), jnp.bfloat16),
+                ((1, 4096, 1, 128), jnp.bfloat16)]
+
+
+_CHIPBENCH = Path(__file__).resolve().parents[1] / "chipbench"
+
+
+def _bench_module(name, rel):
+    spec = importlib.util.spec_from_file_location(name, _CHIPBENCH / rel)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _bench_hlo():
     """The benchmark's reader of Pallas calls in compiled HLO."""
-    path = Path(__file__).resolve().parents[1] / "chipbench" / "hlo.py"
-    spec = importlib.util.spec_from_file_location("chipbench_hlo", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _bench_module("chipbench_hlo", "hlo.py")
 
 
 def _flash_calls(text):
@@ -138,3 +150,35 @@ def test_ssd_scan_compiles_for_v5e(one_chip, grad):
     if grad:
         fn = _grad_of_sum(fn, (0, 1, 2, 3, 4))
     assert "tpu_custom_call" in _compile_text(fn, one_chip, *_MAMBA_SSD)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_ssd_scan_granite_compiles_for_v5e(one_chip, monkeypatch, grad):
+    """At granite-4.0-h-micro's shapes the one ``ssd_scan`` call keeps the
+    operands the benchmark's count reads (``x`` and ``B`` by position,
+    every operand's bytes), so its operations and bytes are the parent's;
+    the forward needs no ``dt`` padded to a 128-lane tile per element (the
+    parent's temporaries: 201,455,616 bytes)."""
+    def fn(x, dt, a, b, c):
+        # under the model's scope, as the step calls it: the scope keeps
+        # ``jit(ssd_scan)/pallas_call`` in the op name under ``jvp``
+        with jax.named_scope("mixer.mamba"):
+            return ssd_scan(x, dt, a, b, c, chunk=256)
+
+    if grad:
+        fn = _grad_of_sum(fn, (0, 1, 2, 3, 4))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in _GRANITE_SSD]
+    compiled = jax.jit(fn).lower(*args).compile()
+    (call,) = [c for c in _bench_hlo().pallas_calls(compiled.as_text())
+               if c["kernel"] == "ssd_scan"]
+    x, _dt, _a, bm, cm = call["operands"]
+    assert x == {"dtype": "bf16", "shape": (1, 64, 4096, 64)}
+    assert bm == cm == {"dtype": "bf16", "shape": (1, 1, 4096, 128)}
+    assert call["result"] == [{"dtype": "bf16", "shape": (1, 64, 4096, 64)}]
+    monkeypatch.syspath_prepend(str(_CHIPBENCH))
+    count = _bench_module("chipbench_ssd_scan", "kernels/ssd_scan.py")
+    assert count.cost(call, {"chunk_size": 256}) == (13_036_421_120,
+                                                     70_254_848)
+    if not grad:
+        assert compiled.memory_analysis().temp_size_in_bytes < 201_455_616
