@@ -139,11 +139,11 @@ def run(quick: bool = False):
                     shuffle=True, seed=0)
     cfg = DPTConfig(num_cpu_cores=2, num_devices=2, min_prefetch=1,
                     max_prefetch=2, num_batches=min(8, num_batches),
-                    epoch=1, cache_budgets=(0, BUDGET))
+                    epoch=1, axes={"cache_budget_bytes": (0, BUDGET)})
     pick = tune(evaluator=LoaderEvaluator(dl, to_device=False),
                 strategy="grid", config=cfg, measure_default=False)
-    assert pick.cache_budget_bytes == BUDGET, \
-        f"DPT grid picked budget {pick.cache_budget_bytes}, not {BUDGET}"
+    picked = pick.axes["cache_budget_bytes"]
+    assert picked == BUDGET, f"DPT grid picked budget {picked}, not {BUDGET}"
 
     # --- ... and the simulator prices the knob the same way ---------------
     sp = StorageProfile(num_items=10_000, item_bytes=1e5,
@@ -155,12 +155,12 @@ def run(quick: bool = False):
                         worker_overhead_bytes=0.2e9)
     sim_cfg = DPTConfig(num_cpu_cores=4, num_devices=2, max_prefetch=2,
                         num_batches=8, epoch=1,
-                        cache_budgets=(0, int(1e9)))
+                        axes={"cache_budget_bytes": (0, int(1e9))})
     sim_pick = tune(evaluator=SimulatorEvaluator(LoaderSimulator(sp, mp),
                                                  batch_size=32),
                     strategy="grid", config=sim_cfg,
                     measure_default=False)
-    assert sim_pick.cache_budget_bytes == int(1e9), \
+    assert sim_pick.axes["cache_budget_bytes"] == int(1e9), \
         "simulator grid kept budget 0 on the RAM-tight warm profile"
 
     rows = [{"epoch": "cold", "workers": 2, "prefetch": 2,
@@ -182,12 +182,11 @@ def run(quick: bool = False):
                  "warm_split_exact": True,
                  "dpt_pick": {"nworker": pick.nworker,
                               "nprefetch": pick.nprefetch,
-                              "cache_budget_bytes":
-                              pick.cache_budget_bytes},
+                              "cache_budget_bytes": picked},
                  "sim_pick": {"nworker": sim_pick.nworker,
                               "nprefetch": sim_pick.nprefetch,
                               "cache_budget_bytes":
-                              sim_pick.cache_budget_bytes}},
+                              sim_pick.axes["cache_budget_bytes"]}},
         "rows": rows,
         "host": {"platform": platform.platform(),
                  "python": sys.version.split()[0],

@@ -20,8 +20,8 @@ from typing import Dict, List
 
 from repro.core import (DPT, DPTConfig, LoaderSimulator, MachineProfile,
                         SimulatorEvaluator)
-from repro.core.search import goodput_tune
 from repro.data.storage import StorageProfile, coco_profile
+from repro.tuning import tune
 
 TITLE = "Goodput-mode tuning (loader >= model, minimal host resources)"
 PAPER_REF = "beyond-paper (DESIGN.md §2 goodput mode)"
@@ -51,8 +51,8 @@ def run(quick: bool = False) -> List[Dict]:
                             batch_size=64)
     max_res = DPT(ev, cfg).run(measure_default=False)
     for step_s in (0.05, 0.2, 1.0):
-        g = goodput_tune(ev, step_time_s=step_s,
-                         num_batches=cfg.num_batches, config=cfg)
+        g = tune(evaluator=ev, strategy="goodput", config=cfg,
+                 step_time_s=step_s, num_batches=cfg.num_batches)
         rows.append({
             "view": "step-sweep", "profile": "coco320", "step_s": step_s,
             "max_workers": max_res.nworker, "goodput_workers": g.nworker,
@@ -77,8 +77,8 @@ def run(quick: bool = False) -> List[Dict]:
                                  batch_size=per_host_batch)
         cfg2 = dataclasses.replace(cfg, num_devices=4)  # 4 local devices
         max2 = DPT(ev2, cfg2).run(measure_default=False)
-        g2 = goodput_tune(ev2, step_time_s=step_s,
-                          num_batches=cfg2.num_batches, config=cfg2)
+        g2 = tune(evaluator=ev2, strategy="goodput", config=cfg2,
+                  step_time_s=step_s, num_batches=cfg2.num_batches)
         rows.append({
             "view": "per-arch", "profile": arch, "step_s": round(step_s, 3),
             "max_workers": max2.nworker, "goodput_workers": g2.nworker,

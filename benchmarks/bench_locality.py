@@ -178,11 +178,12 @@ def run(quick: bool = False):
                     shuffle=True, seed=0)
     cfg = DPTConfig(num_cpu_cores=2, num_devices=2, min_prefetch=1,
                     max_prefetch=2, num_batches=min(8, num_batches),
-                    epoch=0, locality_chunks=(0, CHUNK))
+                    epoch=0, axes={"locality_chunk": (0, CHUNK)})
     pick = tune(evaluator=LoaderEvaluator(dl, to_device=False),
                 strategy="grid", config=cfg, measure_default=False)
-    assert pick.locality_chunk == CHUNK, \
-        f"DPT grid picked locality {pick.locality_chunk}, expected {CHUNK}"
+    picked = pick.axes["locality_chunk"]
+    assert picked == CHUNK, \
+        f"DPT grid picked locality {picked}, expected {CHUNK}"
 
     # --- multi-host layout gate (host-major vs strided, DESIGN.md §6) ------
     mh_rows = multihost_rows(n)
@@ -206,7 +207,7 @@ def run(quick: bool = False):
                  "adjacent_pair_ceiling": round(adj_ceiling, 5),
                  "dpt_pick": {"nworker": pick.nworker,
                               "nprefetch": pick.nprefetch,
-                              "locality_chunk": pick.locality_chunk}},
+                              "locality_chunk": picked}},
         "multihost": {"chunk": MULTIHOST_CHUNK,
                       "required_run_len_min": MULTIHOST_GATE
                       * MULTIHOST_CHUNK,

@@ -14,8 +14,8 @@ from typing import Dict, List
 
 from repro.core import (DPT, DPTConfig, LoaderSimulator, MachineProfile,
                         SimulatorEvaluator)
-from repro.core.search import successive_halving, tuned_with_warmstart
 from repro.data.storage import cifar10_profile, coco_profile
+from repro.tuning import tune
 
 TITLE = "Tuning cost: grid vs successive-halving vs warmstart+hillclimb"
 PAPER_REF = "beyond-paper (search.py)"
@@ -55,7 +55,7 @@ def run(quick: bool = False) -> List[Dict]:
 
         # successive halving
         ev = fresh_ev()
-        sh = successive_halving(ev, config=cfg)
+        sh = tune(evaluator=ev, strategy="successive_halving", config=cfg)
         # re-measure SH's pick at the full budget for a fair regret
         t_sh = ev(sh.nworker, sh.nprefetch, num_batches=cfg.num_batches,
                   epoch=epoch).seconds
@@ -68,8 +68,8 @@ def run(quick: bool = False) -> List[Dict]:
 
         # cost-model warmstart + coordinate hillclimb
         ev = fresh_ev()
-        hc = tuned_with_warmstart(ev, storage, MACHINE, batch_size=batch,
-                                  config=cfg)
+        hc = tune(evaluator=ev, strategy="warmstart_hillclimb", config=cfg,
+                  storage=storage, machine=MACHINE, batch_size=batch)
         t_hc = ev(hc.nworker, hc.nprefetch, num_batches=cfg.num_batches,
                   epoch=epoch).seconds
         rows.append({"profile": name, "strategy": "warmstart+hillclimb",

@@ -14,7 +14,7 @@ epoch through the thread pool with the slow lane off vs on, at equal
   arrives or in which order);
 * an equal-threads baseline (all lane workers folded into the fast pool)
   is recorded alongside — the win is isolation, not extra parallelism;
-* a DPT grid over (workers, prefetch, slow_lanes) on the simulator's
+* a DPT grid over (workers, prefetch, slow_lane_workers) on the simulator's
   heavy-tailed decode profile picks a nonzero lane width, and zero on the
   uniform profile (the fifth axis resolves, and only where it should);
 * the serving rider: a ``BatchingFrontend`` with ``slow_lane=True``
@@ -116,7 +116,8 @@ def dpt_lane_pick(heavy: bool):
     sim = LoaderSimulator(sp, MachineProfile(
         physical_cores=8, logical_cores=8, reserved_cores=0, num_devices=2))
     cfg = DPTConfig(num_cpu_cores=8, num_devices=2, min_prefetch=1,
-                    max_prefetch=2, num_batches=64, slow_lanes=(0, 1, 2, 3))
+                    max_prefetch=2, num_batches=64,
+                    axes={"slow_lane_workers": (0, 1, 2, 3)})
     return tune(evaluator=SimulatorEvaluator(sim, batch_size=4),
                 strategy="grid", config=cfg, measure_default=False)
 
@@ -200,11 +201,12 @@ def run(quick: bool = False):
     # --- the DPT fifth axis resolves (and only on the straggler profile) --
     heavy_pick = dpt_lane_pick(heavy=True)
     uniform_pick = dpt_lane_pick(heavy=False)
-    assert heavy_pick.slow_lane_workers > 0, \
+    heavy_lanes = heavy_pick.axes["slow_lane_workers"]
+    uniform_lanes = uniform_pick.axes["slow_lane_workers"]
+    assert heavy_lanes > 0, \
         "DPT grid never priced a slow lane on the heavy-tailed profile"
-    assert uniform_pick.slow_lane_workers == 0, \
-        f"DPT grid spent {uniform_pick.slow_lane_workers} lane workers " \
-        "on a uniform profile"
+    assert uniform_lanes == 0, \
+        f"DPT grid spent {uniform_lanes} lane workers on a uniform profile"
 
     # --- the serving rider ------------------------------------------------
     serve = serving_rider()
@@ -222,9 +224,8 @@ def run(quick: bool = False):
                  "dpt_pick_heavy": {
                      "nworker": heavy_pick.nworker,
                      "nprefetch": heavy_pick.nprefetch,
-                     "slow_lane_workers": heavy_pick.slow_lane_workers},
-                 "dpt_pick_uniform": {
-                     "slow_lane_workers": uniform_pick.slow_lane_workers}},
+                     "slow_lane_workers": heavy_lanes},
+                 "dpt_pick_uniform": {"slow_lane_workers": uniform_lanes}},
         "serving": serve,
         "rows": rows,
         "host": {"platform": platform.platform(),

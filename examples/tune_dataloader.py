@@ -12,13 +12,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import (DPT, DPTConfig, LoaderSimulator, MachineProfile,
                         SimulatorEvaluator, default_params)
-from repro.core.search import successive_halving, tuned_with_warmstart
 from repro.data.storage import cifar10_profile, coco_profile
+from repro.tuning import tune
 
 MACHINE = MachineProfile()    # the paper's i7-8700K / 64 GB / 1 GPU testbed
 
 
-def tune(profile, batch, epoch, label):
+def paper_grid(profile, batch, epoch, label):
     ev = SimulatorEvaluator(LoaderSimulator(profile, MACHINE),
                             batch_size=batch)
     cfg = DPTConfig(num_cpu_cores=12, num_devices=1, max_prefetch=8,
@@ -33,13 +33,13 @@ def tune(profile, batch, epoch, label):
 
 def main() -> None:
     print("== CIFAR-10 (paper Fig 2: optimum ~10 workers, ~1.3x) ==")
-    tune(cifar10_profile(), 32, epoch=1, label="cifar10 b32 warm")
+    paper_grid(cifar10_profile(), 32, epoch=1, label="cifar10 b32 warm")
 
     print("\n== COCO resolutions (paper Table 1 regimes) ==")
     for res_px in (80, 160, 320, 640):
-        tune(coco_profile(res_px), 32, epoch=0,
-             label=f"coco {res_px}px b32 cold")
-    tune(coco_profile(80), 32, epoch=1, label="coco 80px b32 warm")
+        paper_grid(coco_profile(res_px), 32, epoch=0,
+                   label=f"coco {res_px}px b32 cold")
+    paper_grid(coco_profile(80), 32, epoch=1, label="coco 80px b32 warm")
 
     print("\n== beyond-paper: same optimum, fewer measurements ==")
     storage = coco_profile(160)
@@ -50,10 +50,10 @@ def main() -> None:
     grid_cost = ev.calls
 
     ev2 = SimulatorEvaluator(LoaderSimulator(storage, MACHINE), batch_size=32)
-    sh = successive_halving(ev2, config=cfg)
+    sh = tune(evaluator=ev2, strategy="successive_halving", config=cfg)
     ev3 = SimulatorEvaluator(LoaderSimulator(storage, MACHINE), batch_size=32)
-    hc = tuned_with_warmstart(ev3, storage, MACHINE, batch_size=32,
-                              config=cfg)
+    hc = tune(evaluator=ev3, strategy="warmstart_hillclimb", config=cfg,
+              storage=storage, machine=MACHINE, batch_size=32)
     print(f"grid search     : ({grid.nworker},{grid.nprefetch}) "
           f"in {grid_cost} measurements")
     print(f"succ. halving   : ({sh.nworker},{sh.nprefetch}) "

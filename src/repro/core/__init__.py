@@ -19,13 +19,10 @@ _EXPORTS = {
     "LoaderEvaluator": "repro.core.evaluators",
     "SimulatorEvaluator": "repro.core.evaluators",
     "DPTCache": "repro.core.cache",
-    "search": "repro.core",
 }
 
 
 def __getattr__(name):
-    if name == "search":
-        return importlib.import_module("repro.core.search")
     if name in _EXPORTS:
         mod = importlib.import_module(_EXPORTS[name])
         return getattr(mod, name)

@@ -23,12 +23,52 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 from repro.core.monitor import MemoryOverflow
 from repro.data.loader import TransferStats
 
 Evaluator = Callable[..., TransferStats]  # (nworker, nprefetch, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class Axis:
+    """One beyond-paper search axis (DESIGN.md §5).
+
+    ``field`` is the ``LoaderParams`` field, the evaluator keyword and the
+    key in ``Trial.axes``/``DPTResult.axes``; ``name`` prefixes the axis's
+    keys in the DPT cache file.  ``shared`` axes shape the shared epoch
+    permutation, so a sharded loader may change them only uniformly
+    through the fleet coordinator.  ``warm`` axes only pay off once an
+    epoch has run, so single-axis sweeps price them at ``max(1, epoch)``.
+    """
+    field: str
+    name: str
+    shared: bool
+    warm: bool
+
+
+# every consumer (grid, cache, online and fleet sweeps, trainer guards)
+# iterates this table; a new axis is one entry here plus the loader's own
+# mechanism behind the evaluator keyword
+AXES: Tuple[Axis, ...] = (
+    Axis("locality_chunk", "locality", shared=True, warm=False),    # §5-6
+    Axis("cache_budget_bytes", "cache", shared=True, warm=True),    # §7
+    Axis("slow_lane_workers", "slow_lane", shared=False, warm=False),  # §9
+)
+AXIS_BY_FIELD: Dict[str, Axis] = {a.field: a for a in AXES}
+
+AxisCandidates = Mapping[str, Tuple[int, ...]]
+
+
+def searched_axes(axes: AxisCandidates) -> List[Tuple[Axis, Tuple[int, ...]]]:
+    """The registry entries ``axes`` gives candidates for, in registry
+    order; an empty candidate tuple leaves the axis unsearched."""
+    unknown = set(axes) - AXIS_BY_FIELD.keys()
+    if unknown:
+        raise ValueError(f"unknown tuner axes {sorted(unknown)}; "
+                         f"known: {list(AXIS_BY_FIELD)}")
+    return [(a, tuple(axes[a.field])) for a in AXES if axes.get(a.field)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,24 +79,10 @@ class DPTConfig:
     min_prefetch: int = 1
     num_batches: int = 32                    # measurement budget per cell
     epoch: int = 0                           # 0 = cold (1st), >=1 = warm
-    # beyond-paper third grid axis (DESIGN.md §5): candidate sampler
-    # locality_chunk values (0 = fully random).  None keeps the search on
-    # the paper's (nWorker, nPrefetch) plane and never passes the kwarg to
-    # the evaluator — existing two-argument evaluators are untouched.
-    locality_chunks: Optional[Tuple[int, ...]] = None
-    # beyond-paper fourth grid axis (DESIGN.md §7): candidate cross-epoch
-    # cache budgets in bytes (0 = cache off).  Same contract: None keeps
-    # the kwarg away from the evaluator entirely.
-    cache_budgets: Optional[Tuple[int, ...]] = None
-    # beyond-paper fifth grid axis (DESIGN.md §9): candidate slow-lane
-    # worker counts (0 = dual-lane off).  Same contract: None keeps the
-    # kwarg away from the evaluator entirely.
-    slow_lanes: Optional[Tuple[int, ...]] = None
-    # beyond-paper sixth grid axis (DESIGN.md §11): candidate GLOBAL batch
-    # geometries (0 = keep the loader's current global batch).  Outermost
-    # of all — geometry changes re-shape every inner measurement.  Same
-    # contract: None never passes the kwarg to the evaluator.
-    geometries: Optional[Tuple[int, ...]] = None
+    # beyond-paper grid axes: registry field -> candidate values.  Empty
+    # keeps the search on the paper's (nWorker, nPrefetch) plane, and an
+    # unsearched axis never reaches the evaluator as a keyword.
+    axes: AxisCandidates = dataclasses.field(default_factory=dict)
 
     def resolve(self) -> Tuple[int, int]:
         n = self.num_cpu_cores
@@ -82,18 +108,9 @@ class Trial:
     # per-batch samples when the evaluator measured wall clock (None for
     # aggregate-only evaluators like the simulator)
     batch_seconds: Optional[List[float]] = None
-    # sampler locality the cell was measured with (0 = random order / the
-    # locality axis was not searched)
-    locality_chunk: int = 0
-    # cross-epoch cache budget the cell was measured with (0 = cache off /
-    # the cache axis was not searched)
-    cache_budget_bytes: int = 0
-    # slow-lane workers the cell was measured with (0 = dual-lane off /
-    # the lane axis was not searched)
-    slow_lane_workers: int = 0
-    # global batch the cell was measured with (0 = the loader's own / the
-    # geometry axis was not searched)
-    global_batch: int = 0
+    # the searched axes' values the cell was measured with (an unsearched
+    # axis is absent)
+    axes: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -103,10 +120,8 @@ class DPTResult:
     optimal_time: float
     trials: List[Trial]
     default_time: Optional[float] = None
-    locality_chunk: int = 0
-    cache_budget_bytes: int = 0
-    slow_lane_workers: int = 0
-    global_batch: int = 0
+    # the winning value of each searched axis
+    axes: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def speedup_vs_default(self) -> Optional[float]:

@@ -993,8 +993,7 @@ class DataLoader:
                               to_device: bool = True,
                               locality_chunk: Optional[int] = None,
                               cache_budget_bytes: Optional[int] = None,
-                              slow_lane_workers: Optional[int] = None,
-                              global_batch: Optional[int] = None
+                              slow_lane_workers: Optional[int] = None
                               ) -> TransferStats:
         """Wall-clock time to deliver ``num_batches`` (storage->host[->HBM]).
 
@@ -1015,12 +1014,6 @@ class DataLoader:
         override: the trial pool runs with that lane width (sharing the
         loader's learned cost tracker — the lane is only as good as its
         predictor), ``self.params`` restored afterwards.
-
-        ``global_batch`` is the geometry axis's measurement-only
-        override: the trial iterates a THROWAWAY sampler with the
-        candidate global batch (even per-host split), so DPT can price
-        batch geometries without touching the live sampler's schedule or
-        position.
         """
         if slow_lane_workers is not None \
                 and slow_lane_workers != self.params.slow_lane_workers:
@@ -1031,28 +1024,14 @@ class DataLoader:
                 return self.measure_transfer_time(
                     num_batches, epoch=epoch, to_device=to_device,
                     locality_chunk=locality_chunk,
-                    cache_budget_bytes=cache_budget_bytes,
-                    global_batch=global_batch)
+                    cache_budget_bytes=cache_budget_bytes)
             finally:
                 self.params = saved
-        trial_sampler = self.sampler
-        if global_batch is not None \
-                and int(global_batch) != self.sampler.gb_for_epoch(epoch):
-            s = self.sampler
-            trial_sampler = ShardedSampler(
-                s.num_items, int(global_batch), shuffle=s.shuffle,
-                seed=s.seed, drop_last=s.drop_last,
-                host_index=s.host_index, host_count=s.host_count,
-                layout=s.layout,
-                shard_sizes=ShardedSampler.even_split(int(global_batch),
-                                                      s.host_count))
-            trial_sampler.load_locality(s.locality_state())
-            trial_sampler.load_cache_plan(s.cache_state())
         # static pre-check (the paper's N/A cells fail before running)
         if self.memory_budget is not None:
             probe = self.dataset.get_batch(
-                trial_sampler.local_indices(epoch, 0, locality_chunk)[:1])
-            est_batch = batch_nbytes(probe) * trial_sampler.local_batch
+                self.sampler.local_indices(epoch, 0, locality_chunk)[:1])
+            est_batch = batch_nbytes(probe) * self.sampler.local_batch
             est = estimate_loader_footprint(
                 est_batch, self.params.num_workers,
                 self.params.prefetch_factor, self.params.device_prefetch)
@@ -1081,7 +1060,7 @@ class DataLoader:
             trial_dataset = self.dataset.with_storage(
                 CachedStorage(self.dataset.storage, trial_tier, admit=True))
 
-        idx_iter = _take(trial_sampler.epoch_iter(epoch, locality_chunk),
+        idx_iter = _take(self.sampler.epoch_iter(epoch, locality_chunk),
                          num_batches)
         # snapshot BEFORE _pool(): worker threads start reading the moment
         # the pool is constructed, and their requests belong to this window.

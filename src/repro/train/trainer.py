@@ -31,7 +31,7 @@ import jax
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core.cache import DPTCache
-from repro.core.dpt import DPTConfig
+from repro.core.dpt import AxisCandidates, DPTConfig, searched_axes
 from repro.core.evaluators import LoaderEvaluator
 from repro.data.loader import DataLoader, LoaderParams
 from repro.distributed.sharding_rules import current_ctx, params_shardings
@@ -60,31 +60,18 @@ class TrainerConfig:
     # largest worker count the startup grid tries (None: os.cpu_count(),
     # which on a VM can count the physical host's cores, not the guest's)
     autotune_num_cpu_cores: Optional[int] = None
-    # candidate sampler locality_chunk values for the startup grid
-    # (DESIGN.md §5).  None keeps the search on the paper's two axes;
-    # include 0 in the tuple so fully-random order stays a candidate —
-    # warm/CPU-bound profiles should be free to reject chunking.
-    # Single-host only: on a sharded fleet the axis is ignored (every host
-    # must slice the SAME epoch permutation, so locality can only change
-    # uniformly via the coordinator, never from a per-host tune).
-    autotune_locality_chunks: Optional[tuple] = None
-    # candidate cache_budget_bytes values for the startup grid's fourth
-    # axis (DESIGN.md §7).  None keeps the cache tier off the search;
-    # include 0 in the tuple so "no cache" stays a candidate.  Single-host
-    # startup only, same as locality: on a fleet the budget changes
-    # uniformly through the coordinator (FleetConfig.cache_budgets).
-    autotune_cache_budgets: Optional[tuple] = None
-    # candidate slow_lane_workers values for the startup grid's fifth
-    # axis (DESIGN.md §9).  None keeps the dual lane off the search;
-    # include 0 in the tuple so "no slow lane" stays a candidate.  The
-    # lane is HOST-LOCAL machinery (it never touches the sampler's epoch
-    # permutation, only which worker decodes a batch), so unlike locality
-    # and cache this axis needs no multi-host guard — only the
-    # grid-strategy guard applies.
-    autotune_slow_lanes: Optional[tuple] = None
+    # beyond-paper axes for the startup grid and the online retunes:
+    # registry field -> candidates (core.dpt.AXES: locality_chunk,
+    # DESIGN.md §5; cache_budget_bytes, §7; slow_lane_workers, §9).
+    # Empty keeps the search on the paper's two axes; include 0 in each
+    # tuple so "off" stays a candidate.  Only the grid strategy searches
+    # them, and a sharded loader drops every ``shared`` axis (every host
+    # must slice the SAME epoch permutation, so those change only
+    # uniformly through the coordinator's ``FleetConfig.axes``).
+    autotune_axes: AxisCandidates = dataclasses.field(default_factory=dict)
     # retune trigger on the per-item cost tail ratio (p99/median of the
     # loader's tracked per-item costs, ~1 uniform; see DESIGN.md §9).
-    # 0 disables; only armed when autotune_slow_lanes is set.
+    # 0 disables; only armed when autotune_axes searches slow_lane_workers.
     retune_tail_ratio_trigger: float = 0.0
     # retune trigger on the loader's windowed fault rate (DESIGN.md §10):
     # fires a re-search when the storage browns out and once more when
@@ -96,7 +83,7 @@ class TrainerConfig:
     # achieving it (cache warmed, topology changed) — no search, applied
     # as an epoch-latched hot swap.  On a fleet the proposal routes to
     # the coordinator instead (locality must change uniformly).  The
-    # single-host OnlineTuner also sweeps autotune_locality_chunks at
+    # single-host OnlineTuner also sweeps autotune_axes' locality_chunk at
     # retune time, so the knob can climb back UP when storage slows.
     adaptive_locality: bool = False
     retune_stall_fraction: float = 0.5   # data-wait/compute drift trigger
@@ -177,64 +164,21 @@ class Trainer:
         mfp = machine_fingerprint()
         dfp = self.loader.dataset.fingerprint()
         strategy = self.cfg.autotune_strategy
-        locality_axis = self.cfg.autotune_locality_chunks
-        if locality_axis and self.loader.sampler.host_count > 1:
-            # per-host tuned chunks would give each host a DIFFERENT epoch
-            # permutation, breaking the cross-host coverage invariant the
-            # fleet relies on (every host must slice the SAME perm).  A
-            # multi-host locality change must arrive uniformly through the
-            # coordinator, not the local startup tune.
-            locality_axis = None
-        if locality_axis and strategy != "grid":
-            # only the grid strategy sweeps DPTConfig.locality_chunks; for
-            # any other strategy the axis is unsearched and the result's
-            # locality_chunk=0 must not be force-applied over the user's
-            locality_axis = None
-        cache_axis = self.cfg.autotune_cache_budgets
-        if cache_axis and (self.loader.sampler.host_count > 1
-                           or strategy != "grid"):
-            # same guards as locality: the cache plan shapes the epoch
-            # permutation (interleaved hot chunks), so a sharded fleet
-            # changes the budget uniformly via the coordinator; and only
-            # the grid strategy sweeps the axis
-            cache_axis = None
-        lane_axis = self.cfg.autotune_slow_lanes
-        if lane_axis and strategy != "grid":
-            # only the grid strategy sweeps DPTConfig.slow_lanes.  No
-            # multi-host guard: the lane split is host-local (it never
-            # touches the shared epoch permutation)
-            lane_axis = None
+        axes = self._tuner_axes() if strategy == "grid" else {}
         cached = None if force else cache.get_params(
-            mfp, dfp, self.loader.global_batch,
-            require_locality=bool(locality_axis),
-            require_cache=bool(cache_axis),
-            with_cache=bool(cache_axis),
-            require_slow_lane=bool(lane_axis),
-            with_slow_lane=bool(lane_axis))
+            mfp, dfp, self.loader.global_batch, axes=tuple(axes))
         if cached is not None:
-            rep = {"num_workers": cached[0], "prefetch_factor": cached[1]}
-            if locality_axis:
-                # only adopt a cached locality when this run searches the
-                # axis — a 2-axis run must not silently reset a user-set
-                # locality_chunk to a stale cached value
-                rep["locality_chunk"] = cached[2]
-            if cache_axis:
-                rep["cache_budget_bytes"] = cached[3]
-            if lane_axis:
-                # the lane width is the LAST element whenever requested
-                rep["slow_lane_workers"] = cached[-1]
-            params = self.loader.params.replace(**rep)
+            # only the searched axes are adopted from the cache: a 2-axis
+            # run must not silently reset a user-set value to a stale one
+            nworker, nprefetch, values = cached
+            params = self.loader.params.replace(
+                num_workers=nworker, prefetch_factor=nprefetch, **values)
             self.loader.with_params(params)
             return params
         ev = LoaderEvaluator(self.loader, to_device=True)
         search_cfg = DPTConfig(max_prefetch=self.cfg.autotune_max_prefetch,
                                num_cpu_cores=self.cfg.autotune_num_cpu_cores,
-                               locality_chunks=(tuple(locality_axis)
-                                                if locality_axis else None),
-                               cache_budgets=(tuple(cache_axis)
-                                              if cache_axis else None),
-                               slow_lanes=(tuple(lane_axis)
-                                           if lane_axis else None))
+                               axes=axes)
         search_cfg = dataclasses.replace(search_cfg, num_batches=(
             adaptive_budget(search_cfg, self.cfg.autotune_budget_batches)))
         if strategy == "grid":
@@ -255,28 +199,24 @@ class Trainer:
                       config=search_cfg, **kwargs)
         cache.put(mfp, dfp, self.loader.global_batch, result)
         self.tune_result = result
-        rep = {"num_workers": result.nworker,
-               "prefetch_factor": result.nprefetch}
-        if locality_axis:
-            rep["locality_chunk"] = result.locality_chunk
-        if cache_axis:
-            rep["cache_budget_bytes"] = result.cache_budget_bytes
-        if lane_axis:
-            rep["slow_lane_workers"] = result.slow_lane_workers
-        params = self.loader.params.replace(**rep)
+        params = self.loader.params.replace(
+            num_workers=result.nworker, prefetch_factor=result.nprefetch,
+            **result.axes)
         self.loader.with_params(params)
         return params
 
+    def _tuner_axes(self) -> Dict[str, tuple]:
+        """``autotune_axes`` as this host may search them: a sharded
+        loader drops every ``shared`` axis, since per-host values would
+        give each host a DIFFERENT epoch permutation and break the
+        cross-host coverage invariant (those axes change uniformly
+        through the coordinator instead)."""
+        sharded = self.loader.sampler.host_count > 1
+        return {a.field: cands
+                for a, cands in searched_axes(self.cfg.autotune_axes)
+                if not (a.shared and sharded)}
+
     def _make_online_tuner(self) -> OnlineTuner:
-        # the online locality axis follows the startup grid's candidate
-        # set; single-host only (fleet mode never builds a local tuner,
-        # and a sharded loader must change locality via the coordinator)
-        chunks = self.cfg.autotune_locality_chunks \
-            if self.loader.sampler.host_count == 1 else None
-        budgets = self.cfg.autotune_cache_budgets \
-            if self.loader.sampler.host_count == 1 else None
-        # the lane axis is host-local, so it needs no host_count guard
-        lanes = self.cfg.autotune_slow_lanes
         return OnlineTuner(
             self.loader,
             evaluator=LoaderEvaluator(self.loader, to_device=True),
@@ -288,9 +228,7 @@ class Trainer:
                 retune_budget_batches=self.cfg.autotune_budget_batches,
                 max_prefetch=self.cfg.autotune_max_prefetch,
                 num_cpu_cores=self.cfg.autotune_num_cpu_cores,
-                locality_chunks=(tuple(chunks) if chunks else None),
-                cache_budgets=(tuple(budgets) if budgets else None),
-                slow_lanes=(tuple(lanes) if lanes else None),
+                axes=self._tuner_axes(),
                 tail_ratio_trigger=self.cfg.retune_tail_ratio_trigger,
                 fault_rate_trigger=self.cfg.retune_fault_rate_trigger))
 

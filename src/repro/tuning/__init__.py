@@ -38,12 +38,8 @@ from repro.tuning.strategies import (  # noqa: F401
 from repro.tuning.locality import (  # noqa: F401
     AdaptiveLocalityConfig,
     AdaptiveLocalityController,
-    cache_win,
-    locality_win,
-    slow_lane_win,
-    sweep_cache,
-    sweep_locality,
-    sweep_slow_lanes,
+    axis_win,
+    sweep_axis,
 )
 from repro.tuning.online import (  # noqa: F401
     GoodputMonitor,

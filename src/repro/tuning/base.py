@@ -1,25 +1,21 @@
 """Unified tuning layer: one protocol, one registry, one front door.
 
-Before this layer, the repo had four tuning entry points with four
-signatures (``DPT.run``, ``search.successive_halving``,
-``search.tuned_with_warmstart``, ``search.goodput_tune``), each carrying
-its own Trial bookkeeping and MemoryOverflow handling.  Now every tuner is
-a :class:`TuningStrategy` registered by name, measured through a shared
-:class:`TrialRecorder`, and reachable through::
+Every tuner is a :class:`TuningStrategy` registered by name, measured
+through a shared :class:`TrialRecorder` (one Trial bookkeeping, one
+MemoryOverflow handling), and reachable through::
 
     from repro.tuning import tune
     result = tune(evaluator=ev, strategy="grid", config=DPTConfig(...))
 
-The legacy entry points still exist and delegate here, so nothing that
-imported them moves — but new call sites (OnlineTuner, the trainer, the
-benchmarks) only need the one function.
+Every call site (OnlineTuner, the trainer, the benchmarks) needs only
+the one function.
 
 How Algorithm 1 maps on:  the paper's grid sweep is the ``"grid"``
 strategy (see ``strategies.GridSearch`` — the loop is a line-for-line port
 of Algorithm 1 with the final worker rung clamped to N); the evaluator it
 measures cells with is unchanged (``core/evaluators.py``); the
-``DPTConfig``/``DPTResult``/``Trial`` dataclasses stay in ``core/dpt.py``
-because they predate the layer and everything imports them from there.
+``DPTConfig``/``DPTResult``/``Trial`` dataclasses and the registry of
+beyond-paper axes (``AXES``) live in ``core/dpt.py``.
 """
 from __future__ import annotations
 
@@ -48,80 +44,40 @@ class TrialRecorder:
 
     def seconds(self, nworker: int, nprefetch: int, *,
                 num_batches: Optional[int] = None,
-                record: bool = True,
-                locality_chunk: Optional[int] = None,
-                cache_budget_bytes: Optional[int] = None,
-                slow_lane_workers: Optional[int] = None,
-                global_batch: Optional[int] = None) -> float:
+                record: bool = True, **axes: int) -> float:
         """Measure one cell; ``math.inf`` on overflow.
 
         ``record=False`` measures without logging a Trial (used for the
         paper's default-parameter reference run, which is not part of the
-        sweep).  ``locality_chunk`` is the beyond-paper third axis,
-        ``cache_budget_bytes`` the fourth, ``slow_lane_workers`` the
-        fifth and ``global_batch`` (elastic geometry) the sixth; each is
-        forwarded to the evaluator ONLY when set, so lower-dimensional
-        searches keep working against evaluators that never heard of
-        them.
+        sweep).  ``axes`` are the beyond-paper axis values of the cell
+        (registry fields, ``core.dpt.AXES``); only the axes given reach
+        the evaluator, so lower-dimensional searches keep working against
+        evaluators that never heard of them.
         """
         nb = self.config.num_batches if num_batches is None else num_batches
-        kw = {}
-        if locality_chunk is not None:
-            kw["locality_chunk"] = locality_chunk
-        if cache_budget_bytes is not None:
-            kw["cache_budget_bytes"] = cache_budget_bytes
-        if slow_lane_workers is not None:
-            kw["slow_lane_workers"] = slow_lane_workers
-        if global_batch is not None:
-            kw["global_batch"] = global_batch
-        chunk = locality_chunk or 0
-        budget = cache_budget_bytes or 0
-        lanes = slow_lane_workers or 0
-        gb = global_batch or 0
         try:
             stats = self.evaluator(nworker, nprefetch, num_batches=nb,
-                                   epoch=self.config.epoch, **kw)
+                                   epoch=self.config.epoch, **axes)
         except MemoryOverflow:
+            stats = None
+        if stats is None or stats.overflowed:
             if record:
                 self.trials.append(Trial(nworker, nprefetch, math.inf,
-                                         overflowed=True,
-                                         locality_chunk=chunk,
-                                         cache_budget_bytes=budget,
-                                         slow_lane_workers=lanes,
-                                         global_batch=gb))
-            return math.inf
-        if stats.overflowed:
-            if record:
-                self.trials.append(Trial(nworker, nprefetch, math.inf,
-                                         overflowed=True,
-                                         locality_chunk=chunk,
-                                         cache_budget_bytes=budget,
-                                         slow_lane_workers=lanes,
-                                         global_batch=gb))
+                                         overflowed=True, axes=dict(axes)))
             return math.inf
         if record:
             self.trials.append(Trial(
                 nworker, nprefetch, stats.seconds,
                 peak_bytes=stats.peak_loader_bytes,
                 batch_seconds=getattr(stats, "batch_seconds", None),
-                locality_chunk=chunk,
-                cache_budget_bytes=budget,
-                slow_lane_workers=lanes,
-                global_batch=gb))
+                axes=dict(axes)))
         return stats.seconds
 
     def result(self, nworker: int, nprefetch: int, optimal_time: float,
                *, default_time: Optional[float] = None,
-               locality_chunk: int = 0,
-               cache_budget_bytes: int = 0,
-               slow_lane_workers: int = 0,
-               global_batch: int = 0) -> DPTResult:
+               axes: Optional[Dict[str, int]] = None) -> DPTResult:
         return DPTResult(nworker, nprefetch, optimal_time, self.trials,
-                         default_time=default_time,
-                         locality_chunk=locality_chunk,
-                         cache_budget_bytes=cache_budget_bytes,
-                         slow_lane_workers=slow_lane_workers,
-                         global_batch=global_batch)
+                         default_time=default_time, axes=dict(axes or {}))
 
 
 def worker_rungs(num_cpu_cores: int, num_devices: int) -> List[int]:

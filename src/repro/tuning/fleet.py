@@ -50,13 +50,15 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.dpt import DPTConfig, DPTResult, MultiHostDPT
+from repro.core.dpt import (AXES, AxisCandidates, DPTConfig, DPTResult,
+                            MultiHostDPT, searched_axes)
 from repro.core.monitor import MemoryOverflow
 from repro.data.loader import DataLoader, LoaderParams, TransferStats
 from repro.data.sampler import ShardedSampler
 from repro.distributed.fault_tolerance import (HeartbeatRegistry,
                                                StragglerDetector, plan_remesh)
 from repro.tuning.base import adaptive_budget
+from repro.tuning.locality import sweep_axis
 from repro.tuning.online import GoodputMonitor
 from repro.tuning.transport import (AgentLink, LeaderLease, LocalTransport,
                                     SnapshotStore, StaleLeaderError,
@@ -149,20 +151,15 @@ class FleetConfig:
     max_prefetch: int = 4
     num_cpu_cores: Optional[int] = None
     num_devices: Optional[int] = None
-    # online locality axis (DESIGN.md §6): candidate sampler chunk sizes a
-    # re-consensus may propose.  Locality can only change UNIFORMLY on a
-    # sharded fleet (every host must slice the same epoch permutation), so
+    # online shared axes (registry field -> candidates, core.dpt.AXES) a
+    # re-consensus may propose: locality (DESIGN.md §6) and the cache
+    # budget (§7).  A shared axis changes UNIFORMLY on a sharded fleet —
+    # locality because every host must slice the same epoch permutation,
+    # the budget because a lockstep fleet runs at the max host time — so
     # the sweep scores candidates by the fleet max and the push pins one
-    # common latch epoch on every host.  None keeps re-consensus on
+    # common latch epoch on every host.  Empty keeps re-consensus on
     # (workers, prefetch).
-    locality_chunks: Optional[Tuple[int, ...]] = None
-    # online cache axis (DESIGN.md §7): candidate cross-epoch cache budgets
-    # a re-consensus may propose.  The budget changes UNIFORMLY too — not
-    # for correctness (each host's tier only serves its own shard) but for
-    # goodput: a lockstep fleet runs at the max host time, so a budget only
-    # helps when every host carries it.  Scored by the fleet max at a warm
-    # epoch; None keeps re-consensus off the axis.
-    cache_budgets: Optional[Tuple[int, ...]] = None
+    axes: AxisCandidates = dataclasses.field(default_factory=dict)
     # fault-plane consensus trigger (DESIGN.md §10): re-consensus fires
     # when any alive host's reported windowed ``fault_rate`` crosses this
     # (edge-triggered: once per excursion, plus once when the last
@@ -190,6 +187,13 @@ class FleetConfig:
     max_barrier_rounds: int = 16     # reshard re-issue cap: a fault-injected
                                      # agent that keeps raising its effective
                                      # barrier errors out instead of spinning
+
+    def __post_init__(self):
+        host_local = [a.field for a, _ in searched_axes(self.axes)
+                      if not a.shared]
+        if host_local:
+            raise ValueError(f"fleet axes must be shared; {host_local} "
+                             "are host-local (tune them per host)")
 
 
 class EventLog:
@@ -461,8 +465,7 @@ class HostAgent:
 
     def knob_state(self) -> Dict[str, Any]:
         p = self.loader.params
-        return {"locality_chunk": p.locality_chunk,
-                "cache_budget_bytes": p.cache_budget_bytes}
+        return {a.field: getattr(p, a.field) for a in AXES if a.shared}
 
     def locality_latch_epoch(self) -> int:
         return self.loader.locality_latch_epoch()
@@ -626,14 +629,10 @@ class HostAgent:
             kw: Dict[str, Any] = {
                 "num_batches": int(args.get("num_batches", 16)),
                 "epoch": int(args.get("epoch", 0))}
-            # forward the extra axes only when set: plain 2-axis
-            # evaluators (and the sweep helpers) do not take them
-            if args.get("locality_chunk") is not None:
-                kw["locality_chunk"] = int(args["locality_chunk"])
-            if args.get("cache_budget_bytes") is not None:
-                kw["cache_budget_bytes"] = int(args["cache_budget_bytes"])
-            if args.get("global_batch") is not None:
-                kw["global_batch"] = int(args["global_batch"])
+            # forward the axes only when set: plain 2-axis evaluators do
+            # not take them
+            kw.update({a.field: int(args[a.field]) for a in AXES
+                       if args.get(a.field) is not None})
             try:
                 stats = self.evaluator(
                     int(args["nworker"]), int(args["nprefetch"]), **kw)
@@ -661,16 +660,11 @@ class _RemoteEvaluator:
 
     def __call__(self, nworker: int, nprefetch: int, *,
                  num_batches: int = 16, epoch: int = 0,
-                 locality_chunk: Optional[int] = None,
-                 cache_budget_bytes: Optional[int] = None,
-                 global_batch: Optional[int] = None) -> TransferStats:
+                 **axes: int) -> TransferStats:
         self.calls += 1
         r = self.proxy._send("measure", {
             "nworker": nworker, "nprefetch": nprefetch,
-            "num_batches": num_batches, "epoch": epoch,
-            "locality_chunk": locality_chunk,
-            "cache_budget_bytes": cache_budget_bytes,
-            "global_batch": global_batch})
+            "num_batches": num_batches, "epoch": epoch, **axes})
         if r.get("overflow"):
             raise MemoryOverflow(r.get("error", "remote overflow"))
         return TransferStats(
@@ -772,9 +766,8 @@ class RemoteAgent:
                 int(self._params["prefetch_factor"]))
 
     def knob_state(self) -> Dict[str, Any]:
-        return {"locality_chunk": int(self._params.get("locality_chunk", 0)),
-                "cache_budget_bytes":
-                    int(self._params.get("cache_budget_bytes", 0))}
+        return {a.field: int(self._params.get(a.field, 0))
+                for a in AXES if a.shared}
 
     def locality_latch_epoch(self) -> int:
         return int(self._send("query", {"what": "locality_latch_epoch"}))
@@ -1081,10 +1074,10 @@ class FleetCoordinator:
         """A host's adaptive locality controller observed a run-length
         collapse.  Locality can only change uniformly, so this requests a
         locality re-consensus — and is DROPPED when the fleet searches no
-        locality axis (``FleetConfig.locality_chunks`` unset): a forced
-        search that cannot touch the knob would just burn goodput on
-        every repeated proposal."""
-        if not self.cfg.locality_chunks:
+        locality axis (no ``locality_chunk`` in ``FleetConfig.axes``): a
+        forced search that cannot touch the knob would just burn goodput
+        on every repeated proposal."""
+        if not self.cfg.axes.get("locality_chunk"):
             return
         self.request_consensus(
             reason=f"locality-run-len-collapse:{host}->{int(chunk)}")
@@ -1235,14 +1228,17 @@ class FleetCoordinator:
                 a.end_trials()
         self.consensus_runs += 1
         won = self._is_fleet_win(fleet, agents)
-        # the online locality axis: sweep chunk candidates at the cell the
-        # fleet will actually run (the winner if it won, else the current
-        # majority cell), scored by the fleet max
+        # the online shared axes: sweep each axis's candidates at the cell
+        # the fleet will actually run (the winner if it won, else the
+        # current majority cell), scored by the fleet max
         cell = fleet.uniform_params if won \
             else self._majority_cell(agents)
-        chunk_win = self._locality_consensus(agents, cell)
-        budget_win = self._cache_consensus(agents, cell)
-        applied = won or chunk_win is not None or budget_win is not None
+        wins: Dict[str, int] = {}
+        for axis, _ in searched_axes(self.cfg.axes):
+            win = self._axis_consensus(axis.field, agents, cell)
+            if win is not None:
+                wins[axis.field] = win
+        applied = won or bool(wins)
         self._backoff = 1 if applied else min(self.cfg.max_backoff,
                                               self._backoff * 2)
         event = {"kind": "consensus", "reason": reason,
@@ -1253,8 +1249,7 @@ class FleetCoordinator:
                  # (False for a locality-only apply: hosts keep their
                  # current cells and only the chunk changes)
                  "cell_applied": won,
-                 "locality_chunk": chunk_win,
-                 "cache_budget_bytes": budget_win,
+                 **{a.field: wins.get(a.field) for a in AXES if a.shared},
                  "applied": applied}
         self.events.append(event)
         if applied:
@@ -1262,13 +1257,10 @@ class FleetCoordinator:
             # the new cache plan for the SAME epoch even when producers
             # straddle a boundary (the interleaved order depends on both)
             latch = max(a.locality_latch_epoch() for a in agents) \
-                if (chunk_win is not None or budget_win is not None) \
-                else None
+                if wins else None
             for a in agents:
                 nw, npf = fleet.uniform_params if won else a.param_cell()
-                a.apply_params(nw, npf, locality_chunk=chunk_win,
-                               locality_epoch=latch,
-                               cache_budget_bytes=budget_win)
+                a.apply_params(nw, npf, locality_epoch=latch, **wins)
             # remember what went out: a host that was partitioned through
             # this push re-syncs from here on reconnect
             self._pushed = {
@@ -1395,33 +1387,31 @@ class FleetCoordinator:
         counts = cls._current_cells(agents)
         return max(counts, key=counts.get)
 
-    def _locality_consensus(self, agents: Sequence[HostAgent],
-                            cell: Tuple[int, int]) -> Optional[int]:
-        """Uniform locality decision: per-host chunk sweeps at ``cell``,
+    def _axis_consensus(self, field: str, agents: Sequence[HostAgent],
+                        cell: Tuple[int, int]) -> Optional[int]:
+        """Uniform decision on one shared axis: per-host sweeps at
+        ``cell`` (a warm axis priced at a warm epoch, see ``sweep_axis``),
         aggregated by the fleet max; the winner must beat the current
-        chunk's own fleet time by ``min_improvement`` and be feasible on
-        every host.  Returns the winning chunk or None (keep)."""
-        if not self.cfg.locality_chunks:
-            return None
-        from repro.tuning.locality import sweep_locality
+        value's own fleet time by ``min_improvement`` and be feasible on
+        every host.  Returns the winning value or None (keep)."""
         cfg = self._search_config()
-        cur = agents[0].knob_state()["locality_chunk"]
+        cur = agents[0].knob_state()[field]
         for a in agents:
             a.begin_trials()
         try:
-            per_host = [sweep_locality(
-                a.evaluator, nworker=cell[0], nprefetch=cell[1],
-                chunks=self.cfg.locality_chunks, current_chunk=cur,
-                num_batches=cfg.num_batches) for a in agents]
+            per_host = [sweep_axis(
+                a.evaluator, field, self.cfg.axes[field], cur,
+                nworker=cell[0], nprefetch=cell[1],
+                num_batches=cfg.num_batches, epoch=cfg.epoch)
+                for a in agents]
         finally:
             for a in agents:
                 a.end_trials()
         fleet_time: Dict[int, float] = {}
         for trials in per_host:
-            for chunk, t in trials.items():
-                fleet_time[chunk] = max(fleet_time.get(chunk, 0.0),
-                                        t.seconds)
-        feasible = {c: s for c, s in fleet_time.items()
+            for v, t in trials.items():
+                fleet_time[v] = max(fleet_time.get(v, 0.0), t.seconds)
+        feasible = {v: s for v, s in fleet_time.items()
                     if math.isfinite(s)}
         if not feasible:
             return None
@@ -1429,49 +1419,7 @@ class FleetCoordinator:
         if best == cur:
             return None
         if cur not in feasible:
-            return best                   # current chunk infeasible somewhere
-        if feasible[best] <= (1.0 - self.cfg.min_improvement) * feasible[cur]:
-            return best
-        return None
-
-    def _cache_consensus(self, agents: Sequence[HostAgent],
-                         cell: Tuple[int, int]) -> Optional[int]:
-        """Uniform cache-budget decision (DESIGN.md §7): per-host budget
-        sweeps at ``cell`` measured at a WARM epoch (a cross-epoch cache
-        prices at 0 cold), aggregated by the fleet max; the winner must
-        beat the current budget's own fleet time by ``min_improvement``
-        and be feasible on every host.  Returns the winning budget or
-        None (keep)."""
-        if not self.cfg.cache_budgets:
-            return None
-        from repro.tuning.locality import sweep_cache
-        cfg = self._search_config()
-        cur = agents[0].knob_state()["cache_budget_bytes"]
-        for a in agents:
-            a.begin_trials()
-        try:
-            per_host = [sweep_cache(
-                a.evaluator, nworker=cell[0], nprefetch=cell[1],
-                budgets=self.cfg.cache_budgets, current_budget=cur,
-                num_batches=cfg.num_batches,
-                epoch=max(1, cfg.epoch)) for a in agents]
-        finally:
-            for a in agents:
-                a.end_trials()
-        fleet_time: Dict[int, float] = {}
-        for trials in per_host:
-            for budget, t in trials.items():
-                fleet_time[budget] = max(fleet_time.get(budget, 0.0),
-                                         t.seconds)
-        feasible = {b: s for b, s in fleet_time.items()
-                    if math.isfinite(s)}
-        if not feasible:
-            return None
-        best = min(feasible, key=feasible.get)
-        if best == cur:
-            return None
-        if cur not in feasible:
-            return best                  # current budget infeasible somewhere
+            return best                   # current value infeasible somewhere
         if feasible[best] <= (1.0 - self.cfg.min_improvement) * feasible[cur]:
             return best
         return None
@@ -1688,9 +1636,8 @@ class FleetCoordinator:
         Historical events restore as plain dicts (ElasticPlan values
         become dicts — they are records, not live objects)."""
         cfgd = dict(state["config"])
-        for k in ("locality_chunks", "cache_budgets"):
-            if cfgd.get(k) is not None:
-                cfgd[k] = tuple(cfgd[k])
+        cfgd["axes"] = {k: tuple(v)
+                        for k, v in (cfgd.get("axes") or {}).items()}
         c = cls(config=FleetConfig(**cfgd), clock=clock)
         c._member_state = dict(state.get("members") or {})
         c.reports = {h: report_from_wire(r)

@@ -3,12 +3,13 @@
 PR 4 made ``locality_chunk`` the startup grid's third axis; this module
 makes it a first-class *online* knob, closed at two speeds:
 
-* **Retune-time sweep** (:func:`sweep_locality` + :func:`locality_win`):
-  when an online re-search runs anyway, candidate chunk sizes are priced
-  at the winning (nWorker, nPrefetch) cell through the measurement-only
-  evaluator override (trials never touch the live epoch schedule), and a
-  significant winner rides the same hot swap — latched at the next epoch
-  boundary by ``ShardedSampler.set_locality``.
+* **Retune-time sweep** (:func:`sweep_axis` + :func:`axis_win`, shared
+  by every registry axis): when an online re-search runs anyway,
+  candidate chunk sizes are priced at the winning (nWorker, nPrefetch)
+  cell through the measurement-only evaluator override (trials never
+  touch the live epoch schedule), and a significant winner rides the
+  same hot swap — latched at the next epoch boundary by
+  ``ShardedSampler.set_locality``.
 
 * **Counter-driven resize** (:class:`AdaptiveLocalityController`): the
   live pipeline already surfaces its achieved coalesced run length
@@ -34,167 +35,60 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.core.dpt import Trial
+from repro.core.dpt import AXIS_BY_FIELD, Trial
 from repro.core.monitor import MemoryOverflow
 from repro.tuning.base import steady_samples, welch_wins
 
 
-def sweep_locality(evaluator, *, nworker: int, nprefetch: int,
-                   chunks: Sequence[int], current_chunk: int,
-                   num_batches: int, epoch: int = 0) -> Dict[int, Trial]:
-    """Price candidate ``locality_chunk`` values at one (worker, prefetch)
-    cell through the evaluator's measurement-only override.
+def sweep_axis(evaluator, field: str, candidates: Sequence[int],
+               current: int, *, nworker: int, nprefetch: int,
+               num_batches: int, epoch: int = 0) -> Dict[int, Trial]:
+    """Price candidate values of one registry axis (``core.dpt.AXES``)
+    at one (worker, prefetch) cell through the evaluator's
+    measurement-only override: a trial never touches the live epoch
+    schedule, the live cache tier or the live pool's lane split.
 
-    The current chunk is always measured too (it is the reference the win
+    The current value is always measured too (it is the reference the win
     test defends), every candidate at the SAME cell — so the comparison
-    isolates the locality axis.  Overflowed cells score ``inf``.
+    isolates the axis.  A ``warm`` axis is priced at ``max(1, epoch)``:
+    a cross-epoch cache only pays off once it has something to serve, so
+    cold pricing would always pick 0.  Overflowed cells score ``inf``.
     """
+    if AXIS_BY_FIELD[field].warm:
+        epoch = max(1, epoch)
     trials: Dict[int, Trial] = {}
-    for chunk in dict.fromkeys([max(0, int(current_chunk)),
-                                *(max(0, int(c)) for c in chunks)]):
+    for v in dict.fromkeys([max(0, int(current)),
+                            *(max(0, int(c)) for c in candidates)]):
         try:
             stats = evaluator(nworker, nprefetch, num_batches=num_batches,
-                              epoch=epoch, locality_chunk=chunk)
+                              epoch=epoch, **{field: v})
             if stats.overflowed:
                 raise MemoryOverflow("overflowed")
-            trials[chunk] = Trial(
+            trials[v] = Trial(
                 nworker, nprefetch, stats.seconds,
                 peak_bytes=stats.peak_loader_bytes,
                 batch_seconds=getattr(stats, "batch_seconds", None),
-                locality_chunk=chunk)
+                axes={field: v})
         except MemoryOverflow:
-            trials[chunk] = Trial(nworker, nprefetch, math.inf,
-                                  overflowed=True, locality_chunk=chunk)
+            trials[v] = Trial(nworker, nprefetch, math.inf,
+                              overflowed=True, axes={field: v})
     return trials
 
 
-def locality_win(trials: Dict[int, Trial], current_chunk: int, *,
-                 min_improvement: float = 0.05) -> Optional[int]:
-    """The locality analogue of ``RetunePolicy.is_win``: the argmin chunk
-    must beat the CURRENT chunk's own measured trial — by a Welch test
-    over per-batch times when both sides carry samples, else by the
-    relative ``min_improvement`` threshold.  Returns the winning chunk,
+def axis_win(trials: Dict[int, Trial], current: int, *,
+             min_improvement: float = 0.05) -> Optional[int]:
+    """The single-axis analogue of ``RetunePolicy.is_win``: the argmin
+    value must beat the CURRENT value's own measured trial — by a Welch
+    test over per-batch times when both sides carry samples, else by the
+    relative ``min_improvement`` threshold.  Returns the winning value,
     or None (keep the current one)."""
-    current_chunk = max(0, int(current_chunk))
-    finite = {c: t for c, t in trials.items() if math.isfinite(t.seconds)}
+    current = max(0, int(current))
+    finite = {v: t for v, t in trials.items() if math.isfinite(t.seconds)}
     if not finite:
         return None
-    best = min(finite, key=lambda c: finite[c].seconds)
-    ref = trials.get(current_chunk)
-    if best == current_chunk:
-        return None
-    if ref is None or not math.isfinite(ref.seconds):
-        return best                       # nothing measured to defend
-    ref_s = steady_samples(ref.batch_seconds)
-    win_s = steady_samples(finite[best].batch_seconds)
-    if len(ref_s) >= 2 and len(win_s) >= 2:
-        return best if welch_wins(ref_s, win_s) else None
-    if finite[best].seconds <= (1.0 - min_improvement) * ref.seconds:
-        return best
-    return None
-
-
-def sweep_cache(evaluator, *, nworker: int, nprefetch: int,
-                budgets: Sequence[int], current_budget: int,
-                num_batches: int, epoch: int = 1) -> Dict[int, Trial]:
-    """Price candidate ``cache_budget_bytes`` values at one (worker,
-    prefetch) cell — the cache analogue of :func:`sweep_locality`.
-
-    Measured at a WARM epoch by default: a cross-epoch cache only pays off
-    from epoch 1 on, so pricing it cold would always pick 0.  Candidates
-    go through the evaluator's measurement-only override (throwaway tiers;
-    the live tier is never polluted).
-    """
-    trials: Dict[int, Trial] = {}
-    for budget in dict.fromkeys([max(0, int(current_budget)),
-                                 *(max(0, int(b)) for b in budgets)]):
-        try:
-            stats = evaluator(nworker, nprefetch, num_batches=num_batches,
-                              epoch=epoch, cache_budget_bytes=budget)
-            if stats.overflowed:
-                raise MemoryOverflow("overflowed")
-            trials[budget] = Trial(
-                nworker, nprefetch, stats.seconds,
-                peak_bytes=stats.peak_loader_bytes,
-                batch_seconds=getattr(stats, "batch_seconds", None),
-                cache_budget_bytes=budget)
-        except MemoryOverflow:
-            trials[budget] = Trial(nworker, nprefetch, math.inf,
-                                   overflowed=True,
-                                   cache_budget_bytes=budget)
-    return trials
-
-
-def cache_win(trials: Dict[int, Trial], current_budget: int, *,
-              min_improvement: float = 0.05) -> Optional[int]:
-    """The cache-axis win test — same contract as :func:`locality_win`:
-    the argmin budget must beat the CURRENT budget's own measured trial
-    (Welch over per-batch samples when available, else the relative
-    threshold).  Returns the winning budget, or None."""
-    current_budget = max(0, int(current_budget))
-    finite = {b: t for b, t in trials.items() if math.isfinite(t.seconds)}
-    if not finite:
-        return None
-    best = min(finite, key=lambda b: finite[b].seconds)
-    ref = trials.get(current_budget)
-    if best == current_budget:
-        return None
-    if ref is None or not math.isfinite(ref.seconds):
-        return best                       # nothing measured to defend
-    ref_s = steady_samples(ref.batch_seconds)
-    win_s = steady_samples(finite[best].batch_seconds)
-    if len(ref_s) >= 2 and len(win_s) >= 2:
-        return best if welch_wins(ref_s, win_s) else None
-    if finite[best].seconds <= (1.0 - min_improvement) * ref.seconds:
-        return best
-    return None
-
-
-def sweep_slow_lanes(evaluator, *, nworker: int, nprefetch: int,
-                     lanes: Sequence[int], current_lanes: int,
-                     num_batches: int, epoch: int = 0) -> Dict[int, Trial]:
-    """Price candidate ``slow_lane_workers`` values at one (worker,
-    prefetch) cell — the dual-lane analogue of :func:`sweep_locality`
-    (DESIGN.md §9).
-
-    Candidates go through the evaluator's measurement-only override, so
-    the live pool's lane split is untouched; the live cost tracker keeps
-    learning through the trials (trial batches are real decodes), which
-    is exactly what makes a warm sweep honest — a cold tracker routes
-    nothing to the slow lane and the candidate measures as pure overhead.
-    """
-    trials: Dict[int, Trial] = {}
-    for k in dict.fromkeys([max(0, int(current_lanes)),
-                            *(max(0, int(s)) for s in lanes)]):
-        try:
-            stats = evaluator(nworker, nprefetch, num_batches=num_batches,
-                              epoch=epoch, slow_lane_workers=k)
-            if stats.overflowed:
-                raise MemoryOverflow("overflowed")
-            trials[k] = Trial(
-                nworker, nprefetch, stats.seconds,
-                peak_bytes=stats.peak_loader_bytes,
-                batch_seconds=getattr(stats, "batch_seconds", None),
-                slow_lane_workers=k)
-        except MemoryOverflow:
-            trials[k] = Trial(nworker, nprefetch, math.inf,
-                              overflowed=True, slow_lane_workers=k)
-    return trials
-
-
-def slow_lane_win(trials: Dict[int, Trial], current_lanes: int, *,
-                  min_improvement: float = 0.05) -> Optional[int]:
-    """The slow-lane win test — same contract as :func:`locality_win`:
-    the argmin lane width must beat the CURRENT width's own measured
-    trial (Welch over per-batch samples when available, else the
-    relative threshold).  Returns the winning width, or None."""
-    current_lanes = max(0, int(current_lanes))
-    finite = {k: t for k, t in trials.items() if math.isfinite(t.seconds)}
-    if not finite:
-        return None
-    best = min(finite, key=lambda k: finite[k].seconds)
-    ref = trials.get(current_lanes)
-    if best == current_lanes:
+    best = min(finite, key=lambda v: finite[v].seconds)
+    ref = trials.get(current)
+    if best == current:
         return None
     if ref is None or not math.isfinite(ref.seconds):
         return best                       # nothing measured to defend

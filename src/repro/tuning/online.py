@@ -38,11 +38,13 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.cache import DPTCache
-from repro.core.dpt import DPTConfig, DPTResult, Trial
+from repro.core.dpt import (AXES, AxisCandidates, DPTConfig, DPTResult,
+                            Trial, searched_axes)
 from repro.core.monitor import MemoryOverflow
 from repro.data.loader import DataLoader, LoaderParams
 from repro.tuning.base import (adaptive_budget, steady_samples, tune,
                                welch_wins)
+from repro.tuning.locality import axis_win, sweep_axis
 from repro.utils.fingerprint import machine_fingerprint
 from repro.utils.spans import span
 
@@ -66,28 +68,20 @@ class OnlineTunerConfig:
     max_backoff: int = 8             # cooldown multiplier cap on no-win
     num_cpu_cores: Optional[int] = None   # override DPTConfig.resolve()
     num_devices: Optional[int] = None
-    # online locality axis (DESIGN.md §6): candidate sampler chunk sizes a
-    # retune may propose.  None keeps retunes on (workers, prefetch) — the
-    # startup grid owns the knob.  When set, each retune prices the
-    # candidates at the winning cell through the measurement-only override
-    # and a significant winner rides the same hot swap (epoch-latched).
-    locality_chunks: Optional[Tuple[int, ...]] = None
-    # online cache axis (DESIGN.md §7): candidate cross-epoch cache budgets
-    # a retune may propose.  Same ownership split as locality: None leaves
-    # the knob to the startup grid.  Candidates are priced at a WARM epoch
-    # through throwaway measurement tiers (the live tier is never polluted)
-    # and a winner resizes the live tier in place via apply_params.
-    cache_budgets: Optional[Tuple[int, ...]] = None
-    # online dual-lane axis (DESIGN.md §9): candidate slow-lane widths a
-    # retune may propose.  Same ownership split; candidates are priced
-    # through the measurement-only override while the live cost tracker
-    # keeps learning through the trials.
-    slow_lanes: Optional[Tuple[int, ...]] = None
+    # online axes (registry field -> candidates, core.dpt.AXES) a retune
+    # may propose.  Empty keeps retunes on (workers, prefetch): the
+    # startup grid owns the knobs.  When set, each retune prices every
+    # axis's candidates at the winning cell through the measurement-only
+    # override, and a significant winner rides the same hot swap
+    # (locality is epoch-latched, a cache budget resizes the live tier in
+    # place, a lane width re-splits the pool).
+    axes: AxisCandidates = dataclasses.field(default_factory=dict)
     # retune trigger on the per-item cost tail (io_counters'
     # ``sample_cost_tail_ratio``: p99 over median of the tracked per-item
     # cost estimates, ~1 uniform, large under a heavy tail).  0 disables;
-    # only armed when ``slow_lanes`` is set — the tail signal exists to
-    # resolve the lane axis, stalls still fire the goodput trigger.
+    # only armed when ``axes`` searches ``slow_lane_workers`` — the tail
+    # signal exists to resolve the lane axis, stalls still fire the
+    # goodput trigger.
     tail_ratio_trigger: float = 0.0
     # retune trigger on the fault plane (DESIGN.md §10): io_counters'
     # windowed ``fault_rate``.  0 disables.  Fires on the way IN (the
@@ -190,7 +184,7 @@ class RetunePolicy:
             return True
         # tail drift: a heavy per-item cost tail is drift even before it
         # shows as a mean stall — only armed when the lane axis exists
-        return bool(self.cfg.slow_lanes
+        return bool(self.cfg.axes.get("slow_lane_workers")
                     and self.cfg.tail_ratio_trigger > 0.0
                     and monitor.tail_ratio > self.cfg.tail_ratio_trigger)
 
@@ -300,85 +294,30 @@ class RetuneExecutor:
         finally:
             self.loader.with_params(orig)
 
-    def sweep_locality(self, nworker: int, nprefetch: int
-                       ) -> Tuple[Optional[int], List[Trial]]:
-        """Price the configured chunk candidates at one cell.
+    def sweep(self, field: str, nworker: int, nprefetch: int
+              ) -> Tuple[Optional[int], List[Trial]]:
+        """Price the configured candidates of one axis at one cell.
 
-        Returns ``(winner, trials)``: the significant winning chunk (None
+        Returns ``(winner, trials)``: the significant winning value (None
         = keep the current one) plus the sweep's trials, so the caller
         can fold them into the retune's DPTResult (the cache reads them
         to tell a searched axis from a blind one).  Trials run through
-        the measurement-only override, so the live epoch schedule is
-        never perturbed; loader params are restored afterwards.
+        the measurement-only override (``sweep_axis``, which also prices
+        a warm axis at a warm epoch); loader params are restored
+        afterwards.
         """
-        if not self.cfg.locality_chunks:
-            return None, []
-        from repro.tuning.locality import locality_win, sweep_locality
         orig = self.loader.params
         cfg = self.search_config()
+        current = getattr(orig, field)
         try:
-            trials = sweep_locality(
-                self.evaluator, nworker=nworker, nprefetch=nprefetch,
-                chunks=self.cfg.locality_chunks,
-                current_chunk=orig.locality_chunk,
+            trials = sweep_axis(
+                self.evaluator, field, self.cfg.axes[field], current,
+                nworker=nworker, nprefetch=nprefetch,
                 num_batches=cfg.num_batches, epoch=cfg.epoch)
         finally:
             self.loader.with_params(orig)
-        win = locality_win(trials, orig.locality_chunk,
-                           min_improvement=self.cfg.min_improvement)
-        return win, list(trials.values())
-
-    def sweep_cache(self, nworker: int, nprefetch: int
-                    ) -> Tuple[Optional[int], List[Trial]]:
-        """Price the configured cache budgets at one cell (DESIGN.md §7).
-
-        Same contract as :meth:`sweep_locality`, one difference: budgets
-        are measured at a WARM epoch (max(1, cfg.epoch)) because a
-        cross-epoch cache only pays off once it has something to serve —
-        cold pricing would always pick 0.  Trials run on throwaway tiers
-        (the evaluator's measurement-only override), so the live tier's
-        contents are never perturbed; loader params are restored.
-        """
-        if not self.cfg.cache_budgets:
-            return None, []
-        from repro.tuning.locality import cache_win, sweep_cache
-        orig = self.loader.params
-        cfg = self.search_config()
-        try:
-            trials = sweep_cache(
-                self.evaluator, nworker=nworker, nprefetch=nprefetch,
-                budgets=self.cfg.cache_budgets,
-                current_budget=orig.cache_budget_bytes,
-                num_batches=cfg.num_batches, epoch=max(1, cfg.epoch))
-        finally:
-            self.loader.with_params(orig)
-        win = cache_win(trials, orig.cache_budget_bytes,
-                        min_improvement=self.cfg.min_improvement)
-        return win, list(trials.values())
-
-    def sweep_slow_lane(self, nworker: int, nprefetch: int
-                        ) -> Tuple[Optional[int], List[Trial]]:
-        """Price the configured slow-lane widths at one cell (DESIGN.md
-        §9).  Same contract as :meth:`sweep_locality`; candidates go
-        through the measurement-only override (the live pool's lane split
-        is untouched) and the live cost tracker keeps learning through
-        the trial decodes, so the sweep prices routing, not a cold lane.
-        """
-        if not self.cfg.slow_lanes:
-            return None, []
-        from repro.tuning.locality import slow_lane_win, sweep_slow_lanes
-        orig = self.loader.params
-        cfg = self.search_config()
-        try:
-            trials = sweep_slow_lanes(
-                self.evaluator, nworker=nworker, nprefetch=nprefetch,
-                lanes=self.cfg.slow_lanes,
-                current_lanes=orig.slow_lane_workers,
-                num_batches=cfg.num_batches, epoch=cfg.epoch)
-        finally:
-            self.loader.with_params(orig)
-        win = slow_lane_win(trials, orig.slow_lane_workers,
-                            min_improvement=self.cfg.min_improvement)
+        win = axis_win(trials, current,
+                       min_improvement=self.cfg.min_improvement)
         return win, list(trials.values())
 
     def apply(self, result: DPTResult,
@@ -405,20 +344,19 @@ class RetuneExecutor:
             # an exact (cell, chunk) trial exists whenever the locality
             # sweep changed the chunk (it measured every candidate at
             # the applied cell) or the policy kept the current cell
+            chunk = params.locality_chunk
             t = next((t for t in result.trials
                       if (t.nworker, t.nprefetch) == applied_cell
-                      and t.locality_chunk == params.locality_chunk
+                      and t.axes.get("locality_chunk", 0) == chunk
                       and math.isfinite(t.seconds)), None)
             if t is not None and (
                     applied_cell != (result.nworker, result.nprefetch)
-                    or params.locality_chunk != result.locality_chunk):
+                    or chunk != result.axes.get("locality_chunk", 0)):
                 opt = t.seconds
             cached = dataclasses.replace(
                 result, nworker=params.num_workers,
                 nprefetch=params.prefetch_factor,
-                locality_chunk=params.locality_chunk,
-                cache_budget_bytes=params.cache_budget_bytes,
-                slow_lane_workers=params.slow_lane_workers,
+                axes={a.field: getattr(params, a.field) for a in AXES},
                 optimal_time=opt)
             self.cache.put(self.machine_fp, self.dataset_fp,
                            self.loader.global_batch, cached)
@@ -484,7 +422,8 @@ class OnlineTuner:
         self.monitor.observe(data_s=data_s, step_s=step_s)
         # feed the loader-side signals once per window (io_counters takes
         # locks; no need to pay them every step)
-        want_tail = self.cfg.slow_lanes and self.cfg.tail_ratio_trigger > 0.0
+        want_tail = bool(self.cfg.axes.get("slow_lane_workers")) \
+            and self.cfg.tail_ratio_trigger > 0.0
         want_fault = self.cfg.fault_rate_trigger > 0.0
         if (want_tail or want_fault) \
                 and self.monitor.steps % self.cfg.window == 0:
@@ -531,36 +470,25 @@ class OnlineTuner:
             self.policy.record_outcome(won=False)
             return None
         won = self.policy.is_win(result, orig)
-        # the online locality axis (DESIGN.md §6): price chunk candidates
-        # at the cell the fleet will actually run — the search winner if
-        # it won, else the current cell — and let a significant chunk win
-        # ride the same hot swap (epoch-latched by the sampler)
+        # the online axes (DESIGN.md §6, §7, §9): price each axis's
+        # candidates at the cell the loader will actually run — the search
+        # winner if it won, else the current cell — and let significant
+        # winners ride the same hot swap
         cell = (result.nworker, result.nprefetch) if won \
             else (orig.num_workers, orig.prefetch_factor)
-        chunk_win, chunk_trials = self.executor.sweep_locality(*cell)
-        result.trials.extend(chunk_trials)
-        # the online cache axis (DESIGN.md §7): price budget candidates at
-        # the same cell — a winner resizes the live tier in place via the
-        # same hot swap (the tier survives apply_params)
-        budget_win, budget_trials = self.executor.sweep_cache(*cell)
-        result.trials.extend(budget_trials)
-        # the online dual-lane axis (DESIGN.md §9): price lane widths at
-        # the same cell — a winner re-splits the pool via the same hot
-        # swap (the cost tracker is loader-owned and survives the swap)
-        lane_win, lane_trials = self.executor.sweep_slow_lane(*cell)
-        result.trials.extend(lane_trials)
-        self.policy.record_outcome(won=won or chunk_win is not None
-                                   or budget_win is not None
-                                   or lane_win is not None)
-        if not won and chunk_win is None and budget_win is None \
-                and lane_win is None:
+        wins: Dict[str, int] = {}
+        for axis, _ in searched_axes(self.cfg.axes):
+            win, trials = self.executor.sweep(axis.field, *cell)
+            result.trials.extend(trials)
+            if win is not None:
+                wins[axis.field] = win
+        self.policy.record_outcome(won=won or bool(wins))
+        if not won and not wins:
             self.history.append({
                 "step": self.monitor.steps, "reason": reason,
                 "outcome": "kept",
                 "params": (orig.num_workers, orig.prefetch_factor),
-                "locality_chunk": orig.locality_chunk,
-                "cache_budget_bytes": orig.cache_budget_bytes,
-                "slow_lane_workers": orig.slow_lane_workers,
+                **{a.field: getattr(orig, a.field) for a in AXES},
                 "optimal_time": result.optimal_time,
                 "measurements": len(result.trials),
                 "search_s": time.perf_counter() - t0,
@@ -568,21 +496,14 @@ class OnlineTuner:
             return None
         params = orig if not won else orig.replace(
             num_workers=result.nworker, prefetch_factor=result.nprefetch)
-        if chunk_win is not None:
-            params = params.replace(locality_chunk=chunk_win)
-        if budget_win is not None:
-            params = params.replace(cache_budget_bytes=budget_win)
-        if lane_win is not None:
-            params = params.replace(slow_lane_workers=lane_win)
+        params = params.replace(**wins)
         params = self.executor.apply(result, params)
         self.retunes += 1
         self.history.append({
             "step": self.monitor.steps, "reason": reason,
             "outcome": "applied",
             "params": (params.num_workers, params.prefetch_factor),
-            "locality_chunk": params.locality_chunk,
-            "cache_budget_bytes": params.cache_budget_bytes,
-            "slow_lane_workers": params.slow_lane_workers,
+            **{a.field: getattr(params, a.field) for a in AXES},
             "optimal_time": result.optimal_time,
             "measurements": len(result.trials),
             "search_s": time.perf_counter() - t0,

@@ -13,16 +13,18 @@
   time merely outpaces the model step; frees cores where the model, not
   the loader, is the bottleneck.
 
-All of these used to live as separately-shaped functions in ``core/dpt.py``
-and ``core/search.py``; those modules now delegate here.
+``core.dpt.DPT.run`` delegates to ``grid``; every other caller goes
+through ``tune(strategy=...)``.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Dict, List, Optional, Tuple
 
-from repro.core.dpt import DPTConfig, DPTResult, default_params
+from repro.core.dpt import (DPTConfig, DPTResult, default_params,
+                            searched_axes)
 from repro.core.monitor import MemoryOverflow
 from repro.core.simulator import LoaderSimulator, MachineProfile
 from repro.data.storage import StorageProfile
@@ -40,59 +42,40 @@ class GridSearch:
     reproduces the paper's comparison against PyTorch defaults and is not
     recorded as a sweep trial.
 
-    Beyond paper: when ``config.locality_chunks`` is set, the same sweep
-    repeats per candidate sampler chunk size — a third, outermost axis
-    (DESIGN.md §5).  ``config.cache_budgets`` adds the fourth axis the
-    same way (DESIGN.md §7), ``config.slow_lanes`` a fifth (DESIGN.md §9)
-    and ``config.geometries`` (candidate global batches, DESIGN.md §11) a
-    sixth, outermost of all.  Left unset (the default), the loop is
-    exactly Algorithm 1 and the evaluator never sees a locality, cache,
-    slow-lane or geometry kwarg.
+    Beyond paper: the sweep repeats for every combination of the axis
+    candidates in ``config.axes`` (``core.dpt.AXES``: locality, cache
+    budget, slow lane), outside the worker loop, the last registry axis
+    outermost (DESIGN.md §5).  With no axes the loop is exactly Algorithm
+    1 and the evaluator never sees an axis keyword.
     """
 
     def tune(self, rec: TrialRecorder, *,
              measure_default: bool = True) -> DPTResult:
         cfg = rec.config
         N, G = cfg.resolve()
-        chunks = cfg.locality_chunks if cfg.locality_chunks else (None,)
-        budgets = cfg.cache_budgets if cfg.cache_budgets else (None,)
-        lanes = cfg.slow_lanes if cfg.slow_lanes else (None,)
-        geoms = cfg.geometries if cfg.geometries else (None,)
+        searched = searched_axes(cfg.axes)[::-1]      # outermost first
         n_worker, n_prefetch = 0, 0
-        n_chunk, n_budget, n_lane, n_geom = 0, 0, 0, 0
+        n_axes = {a.field: 0 for a, _ in searched}
         optimal_time = math.inf
-        for g in geoms:                            # beyond-paper axis 6
-            for s in lanes:                        # beyond-paper axis 5
-                for b in budgets:                  # beyond-paper axis 4
-                    for c in chunks:               # beyond-paper axis 3
-                        for i in worker_rungs(N, G):       # lines 4-5
-                            j = cfg.min_prefetch           # line 6
-                            while j <= cfg.max_prefetch:   # line 7
-                                t = rec.seconds(i, j,      # lines 8, 12
-                                                locality_chunk=c,
-                                                cache_budget_bytes=b,
-                                                slow_lane_workers=s,
-                                                global_batch=g)
-                                if not math.isfinite(t):   # lines 9-10
-                                    break
-                                if t < optimal_time:       # lines 14-17
-                                    optimal_time = t
-                                    n_worker, n_prefetch = i, j
-                                    n_chunk = c or 0
-                                    n_budget = b or 0
-                                    n_lane = s or 0
-                                    n_geom = g or 0
-                                j += 1                     # line 19
+        for values in itertools.product(*(c for _, c in searched)):
+            point = {a.field: v for (a, _), v in zip(searched, values)}
+            for i in worker_rungs(N, G):               # lines 4-5
+                j = cfg.min_prefetch                   # line 6
+                while j <= cfg.max_prefetch:           # line 7
+                    t = rec.seconds(i, j, **point)     # lines 8, 12
+                    if not math.isfinite(t):           # lines 9-10
+                        break
+                    if t < optimal_time:               # lines 14-17
+                        optimal_time = t
+                        n_worker, n_prefetch = i, j
+                        n_axes = point
+                    j += 1                             # line 19
         default_time = None
         if measure_default:
             dw, dp = default_params(N)
             default_time = rec.seconds(dw, dp, record=False)
         return rec.result(n_worker, n_prefetch, optimal_time,
-                          default_time=default_time,
-                          locality_chunk=n_chunk,
-                          cache_budget_bytes=n_budget,
-                          slow_lane_workers=n_lane,
-                          global_batch=n_geom)
+                          default_time=default_time, axes=n_axes)
 
 
 @register_strategy("successive_halving")

@@ -20,7 +20,7 @@ from repro.data import DataLoader, LoaderParams
 from repro.data.arena import SlabArena
 from repro.data.cache import CachedStorage, CacheTier, plan_hot_chunks
 from repro.data.storage import ArrayStorage, StorageProfile
-from repro.tuning import cache_win, sweep_cache, tune
+from repro.tuning import axis_win, sweep_axis, tune
 
 
 def _items(n, width=4):
@@ -240,17 +240,16 @@ def test_sweep_cache_prices_warm_and_cache_win():
     ev = make_table_evaluator(
         lambda i, j, c, b, e: (1.0 - (0.4 if b and e >= 1 else 0.0)
                                + 0.2 * (b == BIG)), cache=True)
-    trials = sweep_cache(ev, nworker=4, nprefetch=2,
-                         budgets=(0, SMALL, BIG), current_budget=0,
-                         num_batches=8)
+    trials = sweep_axis(ev, "cache_budget_bytes", (0, SMALL, BIG), 0,
+                        nworker=4, nprefetch=2, num_batches=8)
     assert set(trials) == {0, SMALL, BIG}
     assert all(e == 1 for e in ev.epochs)     # priced at a WARM epoch
-    assert all(t.cache_budget_bytes == b for b, t in trials.items())
-    assert cache_win(trials, 0) == SMALL
-    assert cache_win(trials, SMALL) is None   # best == current: keep
+    assert all(t.axes["cache_budget_bytes"] == b for b, t in trials.items())
+    assert axis_win(trials, 0) == SMALL
+    assert axis_win(trials, SMALL) is None    # best == current: keep
     # an insignificant gap keeps the current budget
     flat = {0: Trial(4, 2, 1.0), SMALL: Trial(4, 2, 0.99)}
-    assert cache_win(flat, 0, min_improvement=0.05) is None
+    assert axis_win(flat, 0, min_improvement=0.05) is None
 
 
 def test_grid_search_four_axis_picks_nonzero_budget():
@@ -259,42 +258,41 @@ def test_grid_search_four_axis_picks_nonzero_budget():
                                - (1.0 if b == SMALL and e >= 1 else 0.0)
                                + (0.5 if b == BIG else 0.0)), cache=True)
     cfg = DPTConfig(num_cpu_cores=4, num_devices=2, max_prefetch=2,
-                    num_batches=4, epoch=1, cache_budgets=(0, SMALL, BIG))
+                    num_batches=4, epoch=1, axes={"cache_budget_bytes": (0, SMALL, BIG)})
     res = tune(evaluator=ev, strategy="grid", config=cfg,
                measure_default=False)
     assert (res.nworker, res.nprefetch) == (4, 1)
-    assert res.cache_budget_bytes == SMALL
-    assert any(t.cache_budget_bytes == SMALL for t in res.trials)
+    assert res.axes["cache_budget_bytes"] == SMALL
+    assert any(t.axes["cache_budget_bytes"] == SMALL for t in res.trials)
     # the axis unset: the evaluator must never see the kwarg (legacy
     # two-arg evaluators keep working) and the result carries budget 0
     legacy = make_table_evaluator(lambda i, j: 4.0 / i + 0.1 * j)
     res2 = tune(evaluator=legacy, strategy="grid",
-                config=dataclasses.replace(cfg, cache_budgets=None),
+                config=dataclasses.replace(cfg, axes={}),
                 measure_default=False)
-    assert res2.cache_budget_bytes == 0
+    assert res2.axes == {}
 
 
 def test_dpt_cache_fourth_axis_backcompat_and_clobber_protection():
     cache = DPTCache()
     searched = DPTResult(4, 2, 0.5, [
-        Trial(4, 2, 1.0, cache_budget_bytes=0),
-        Trial(4, 2, 0.5, cache_budget_bytes=SMALL)],
-        cache_budget_bytes=SMALL)
+        Trial(4, 2, 1.0, axes={"cache_budget_bytes": 0}),
+        Trial(4, 2, 0.5, axes={"cache_budget_bytes": SMALL})],
+        axes={"cache_budget_bytes": SMALL})
     cache.put("m", "d", 32, searched)
-    # the legacy 3-tuple contract is unchanged
-    assert cache.get_params("m", "d", 32) == (4, 2, 0)
-    assert cache.get_params("m", "d", 32, with_cache=True) \
-        == (4, 2, 0, SMALL)
-    assert cache.get_params("m", "d", 32, require_cache=True,
-                            with_cache=True) == (4, 2, 0, SMALL)
+    # an axis-blind read still hits, with no axis values
+    assert cache.get_params("m", "d", 32) == (4, 2, {})
+    assert cache.get_params("m", "d", 32, axes=("cache_budget_bytes",)) \
+        == (4, 2, {"cache_budget_bytes": SMALL})
     # a budget-blind refinement must not clobber the searched budget
     blind = DPTResult(6, 1, 0.4, [Trial(6, 1, 0.4)])
     cache.put("m", "d", 32, blind)
-    assert cache.get_params("m", "d", 32, with_cache=True) \
-        == (6, 1, 0, SMALL)
-    # a fresh entry whose search never swept the axis misses require_cache
+    assert cache.get_params("m", "d", 32, axes=("cache_budget_bytes",)) \
+        == (6, 1, {"cache_budget_bytes": SMALL})
+    # a fresh entry whose search never swept the axis misses the read
     cache.put("m2", "d", 32, blind)
-    assert cache.get_params("m2", "d", 32, require_cache=True) is None
+    assert cache.get_params("m2", "d", 32,
+                            axes=("cache_budget_bytes",)) is None
 
 
 def test_online_retune_sweeps_and_applies_cache_budget():
@@ -308,7 +306,7 @@ def test_online_retune_sweeps_and_applies_cache_budget():
         cache=True)
     tuner = OnlineTuner(dl, evaluator=ev, config=OnlineTunerConfig(
         num_cpu_cores=4, num_devices=2, max_prefetch=2,
-        retune_budget_batches=2, cache_budgets=(0, SMALL)))
+        retune_budget_batches=2, axes={"cache_budget_bytes": (0, SMALL)}))
     params = tuner.force_retune(reason="test")
     assert params is not None
     assert params.cache_budget_bytes == SMALL
@@ -324,7 +322,7 @@ def test_fleet_consensus_pushes_uniform_cache_budget():
     coord = FleetCoordinator(config=FleetConfig(
         heartbeat_timeout_s=30.0, warmup_steps=1, cooldown_steps=1,
         num_cpu_cores=4, num_devices=2, max_prefetch=2,
-        retune_budget_batches=2, cache_budgets=(0, SMALL)))
+        retune_budget_batches=2, axes={"cache_budget_bytes": (0, SMALL)}))
     agents = []
     for h in range(hosts):
         dl = DataLoader(make_index_dataset(n), gb, shuffle=True, seed=3,
@@ -417,14 +415,14 @@ def test_simulated_grid_picks_budget_warm_and_zero_cold():
     from repro.core.evaluators import SimulatorEvaluator
     ev = SimulatorEvaluator(LoaderSimulator(_SP, _MP_TIGHT), batch_size=32)
     cfg = DPTConfig(num_cpu_cores=4, num_devices=2, max_prefetch=2,
-                    num_batches=8, epoch=1, cache_budgets=(0, int(1e9)))
+                    num_batches=8, epoch=1, axes={"cache_budget_bytes": (0, int(1e9))})
     warm = tune(evaluator=ev, strategy="grid", config=cfg,
                 measure_default=False)
-    assert warm.cache_budget_bytes == int(1e9)
+    assert warm.axes["cache_budget_bytes"] == int(1e9)
     cold = tune(evaluator=ev, strategy="grid",
                 config=dataclasses.replace(cfg, epoch=0),
                 measure_default=False)
-    assert cold.cache_budget_bytes == 0       # ties resolve to no cache
+    assert cold.axes["cache_budget_bytes"] == 0   # ties resolve to no cache
 
 
 # --------------------------------------------------------------------------
@@ -432,8 +430,8 @@ def test_simulated_grid_picks_budget_warm_and_zero_cold():
 # --------------------------------------------------------------------------
 def test_trainer_guards_cache_axis_like_locality():
     from repro.train.trainer import TrainerConfig
-    cfg = TrainerConfig(autotune_cache_budgets=(0, SMALL))
-    assert cfg.autotune_cache_budgets == (0, SMALL)
+    cfg = TrainerConfig(autotune_axes={"cache_budget_bytes": (0, SMALL)})
+    assert cfg.autotune_axes == {"cache_budget_bytes": (0, SMALL)}
     # the online tuner inherits the axis on a single host
     from repro.train.trainer import Trainer
     dl = DataLoader(make_index_dataset(32), 8, shuffle=True, seed=0,
@@ -441,10 +439,10 @@ def test_trainer_guards_cache_axis_like_locality():
     t = Trainer.__new__(Trainer)
     t.loader, t.cfg = dl, cfg
     tuner = t._make_online_tuner()
-    assert tuner.cfg.cache_budgets == (0, SMALL)
+    assert tuner.cfg.axes == {"cache_budget_bytes": (0, SMALL)}
     # sharded: the axis must stay off host-local retunes
     dl2 = DataLoader(make_index_dataset(32), 8, shuffle=True, seed=0,
                      params=LoaderParams(num_workers=1),
                      host_index=0, host_count=2)
     t.loader = dl2
-    assert t._make_online_tuner().cfg.cache_budgets is None
+    assert t._make_online_tuner().cfg.axes == {}

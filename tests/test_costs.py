@@ -349,21 +349,21 @@ def test_dpt_grid_resolves_lane_axis():
         ev = SimulatorEvaluator(_sim(profile), batch_size=4)
         cfg = DPTConfig(num_cpu_cores=8, num_devices=2, min_prefetch=1,
                         max_prefetch=2, num_batches=64,
-                        slow_lanes=(0, 1, 2, 3))
+                        axes={"slow_lane_workers": (0, 1, 2, 3)})
         return tune(evaluator=ev, strategy="grid", config=cfg,
                     measure_default=False)
 
     heavy = pick(_decode_heavy_profile().with_heavy_tail(fraction=0.03,
                                                          mult=100.0))
-    assert heavy.slow_lane_workers > 0
-    assert any(t.slow_lane_workers for t in heavy.trials)
+    assert heavy.axes["slow_lane_workers"] > 0
+    assert any(t.axes["slow_lane_workers"] for t in heavy.trials)
     uniform = pick(_decode_heavy_profile())
-    assert uniform.slow_lane_workers == 0
+    assert uniform.axes["slow_lane_workers"] == 0
 
 
 def test_dpt_grid_without_lane_axis_never_passes_kwarg():
-    """slow_lanes=None keeps the search lane-blind: evaluators that never
-    heard of the axis must keep working (the None-contract)."""
+    """No lane axis in ``axes`` keeps the search lane-blind: evaluators
+    that never heard of the axis must keep working."""
     from conftest import make_table_evaluator
     from repro.core.dpt import DPTConfig
     from repro.tuning import tune
@@ -372,8 +372,8 @@ def test_dpt_grid_without_lane_axis_never_passes_kwarg():
              config=DPTConfig(num_cpu_cores=4, num_devices=2,
                               max_prefetch=2),
              measure_default=False)
-    assert r.slow_lane_workers == 0
-    assert all(t.slow_lane_workers == 0 for t in r.trials)
+    assert r.axes == {}
+    assert all(t.axes == {} for t in r.trials)
 
 
 # --------------------------------------------------------------------------
@@ -391,35 +391,35 @@ def _lane_table_evaluator(fn):
 
 
 def test_sweep_slow_lanes_and_win():
-    from repro.tuning import slow_lane_win, sweep_slow_lanes
+    from repro.tuning import axis_win, sweep_axis
     ev = _lane_table_evaluator(lambda i, j, k: 1.0 / (1 + k))
-    trials = sweep_slow_lanes(ev, nworker=2, nprefetch=1, lanes=(0, 2, 4),
-                              current_lanes=0, num_batches=8)
+    trials = sweep_axis(ev, "slow_lane_workers", (0, 2, 4), 0,
+                        nworker=2, nprefetch=1, num_batches=8)
     assert set(trials) == {0, 2, 4}
-    assert all(t.slow_lane_workers == k for k, t in trials.items())
-    assert slow_lane_win(trials, 0) == 4
+    assert all(t.axes["slow_lane_workers"] == k for k, t in trials.items())
+    assert axis_win(trials, 0) == 4
 
 
 def test_slow_lane_win_defends_current():
-    from repro.tuning import slow_lane_win
+    from repro.tuning import axis_win
     from repro.core.dpt import Trial
     # a 2% improvement does not clear the 5% threshold
-    trials = {0: Trial(2, 1, 1.00, slow_lane_workers=0),
-              2: Trial(2, 1, 0.98, slow_lane_workers=2)}
-    assert slow_lane_win(trials, 0) is None
+    trials = {0: Trial(2, 1, 1.00, axes={"slow_lane_workers": 0}),
+              2: Trial(2, 1, 0.98, axes={"slow_lane_workers": 2})}
+    assert axis_win(trials, 0) is None
     # the current width being the argmin is never a "win"
-    trials[2] = Trial(2, 1, 1.50, slow_lane_workers=2)
-    assert slow_lane_win(trials, 0) is None
+    trials[2] = Trial(2, 1, 1.50, axes={"slow_lane_workers": 2})
+    assert axis_win(trials, 0) is None
     # an overflowed candidate never wins
-    trials = {0: Trial(2, 1, 1.0, slow_lane_workers=0),
+    trials = {0: Trial(2, 1, 1.0, axes={"slow_lane_workers": 0}),
               2: Trial(2, 1, math.inf, overflowed=True,
-                       slow_lane_workers=2)}
-    assert slow_lane_win(trials, 0) is None
+                       axes={"slow_lane_workers": 2})}
+    assert axis_win(trials, 0) is None
 
 
 def test_sweep_slow_lanes_handles_overflow():
     from repro.core.monitor import MemoryOverflow
-    from repro.tuning import sweep_slow_lanes
+    from repro.tuning import sweep_axis
 
     def ev(i, j, *, num_batches=16, epoch=0, slow_lane_workers=None):
         if (slow_lane_workers or 0) > 2:
@@ -427,8 +427,8 @@ def test_sweep_slow_lanes_handles_overflow():
         from repro.data.loader import TransferStats
         return TransferStats(1.0, num_batches, 0)
 
-    trials = sweep_slow_lanes(ev, nworker=2, nprefetch=1, lanes=(0, 2, 4),
-                              current_lanes=0, num_batches=8)
+    trials = sweep_axis(ev, "slow_lane_workers", (0, 2, 4), 0,
+                        nworker=2, nprefetch=1, num_batches=8)
     assert trials[4].overflowed and math.isinf(trials[4].seconds)
     assert not trials[2].overflowed
 
@@ -438,9 +438,9 @@ def test_sweep_slow_lanes_handles_overflow():
 # --------------------------------------------------------------------------
 def _result(lane, *, searched):
     from repro.core.dpt import DPTResult, Trial
-    trials = [Trial(2, 1, 1.0, slow_lane_workers=k)
+    trials = [Trial(2, 1, 1.0, axes={"slow_lane_workers": k})
               for k in ((0, lane) if searched else (0,))]
-    return DPTResult(2, 1, 1.0, trials, slow_lane_workers=lane)
+    return DPTResult(2, 1, 1.0, trials, axes={"slow_lane_workers": lane})
 
 
 def test_dpt_cache_lane_axis_roundtrip(tmp_path):
@@ -448,20 +448,21 @@ def test_dpt_cache_lane_axis_roundtrip(tmp_path):
     path = str(tmp_path / "dpt.json")
     cache = DPTCache(path)
     cache.put("m", "d", 32, _result(2, searched=True))
-    got = cache.get_params("m", "d", 32, with_slow_lane=True,
-                           require_slow_lane=True)
-    assert got is not None and got[-1] == 2
+    got = cache.get_params("m", "d", 32, axes=("slow_lane_workers",))
+    assert got is not None and got[2]["slow_lane_workers"] == 2
     # persists across a reload
-    assert DPTCache(path).get_params("m", "d", 32,
-                                     with_slow_lane=True)[-1] == 2
+    assert DPTCache(path).get_params(
+        "m", "d", 32, axes=("slow_lane_workers",))[2] == {
+            "slow_lane_workers": 2}
 
 
 def test_dpt_cache_lane_blind_entry_is_stale():
     from repro.core.cache import DPTCache
     cache = DPTCache()
     cache.put("m", "d", 32, _result(0, searched=False))
-    assert cache.get_params("m", "d", 32, require_slow_lane=True) is None
-    assert cache.get_params("m", "d", 32) is not None   # still fine 3-axis
+    assert cache.get_params("m", "d", 32, axes=("slow_lane_workers",)) \
+        is None
+    assert cache.get_params("m", "d", 32) is not None   # fine axis-blind
 
 
 def test_dpt_cache_lane_blind_refinement_never_clobbers():
@@ -471,9 +472,8 @@ def test_dpt_cache_lane_blind_refinement_never_clobbers():
     # an online 2-axis retune refines (workers, prefetch) lane-blind;
     # the searched lane width must survive
     cache.put("m", "d", 32, _result(0, searched=False))
-    got = cache.get_params("m", "d", 32, with_slow_lane=True,
-                           require_slow_lane=True)
-    assert got is not None and got[-1] == 2
+    got = cache.get_params("m", "d", 32, axes=("slow_lane_workers",))
+    assert got is not None and got[2]["slow_lane_workers"] == 2
 
 
 # --------------------------------------------------------------------------
@@ -484,7 +484,7 @@ def test_tail_ratio_trigger_arms_only_with_lanes():
                                      RetunePolicy)
     mon = GoodputMonitor()
     mon.note_tail(50.0)
-    armed = RetunePolicy(OnlineTunerConfig(slow_lanes=(0, 2),
+    armed = RetunePolicy(OnlineTunerConfig(axes={"slow_lane_workers": (0, 2)},
                                            tail_ratio_trigger=10.0))
     assert armed.drifted(mon)
     below = GoodputMonitor()
@@ -494,7 +494,7 @@ def test_tail_ratio_trigger_arms_only_with_lanes():
     # never act on it
     disarmed = RetunePolicy(OnlineTunerConfig(tail_ratio_trigger=10.0))
     assert not disarmed.drifted(mon)
-    off = RetunePolicy(OnlineTunerConfig(slow_lanes=(0, 2)))
+    off = RetunePolicy(OnlineTunerConfig(axes={"slow_lane_workers": (0, 2)}))
     assert not off.drifted(mon)
 
 
@@ -509,7 +509,7 @@ def test_online_tuner_observe_feeds_tail_signal():
     for e in range(2):                 # warm the tracker
         list(dl.host_batches(epoch=e, num_batches=n // gb))
     cfg = OnlineTunerConfig(window=4, warmup_steps=10**6,  # never searches
-                            slow_lanes=(0, 2), tail_ratio_trigger=1.5)
+                            axes={"slow_lane_workers": (0, 2)}, tail_ratio_trigger=1.5)
     tuner = OnlineTuner(dl, evaluator=None, config=cfg)
     for _ in range(cfg.window):
         tuner.observe(data_s=0.0, step_s=0.01)

@@ -9,11 +9,9 @@ from repro.core import (DPT, DPTConfig, LoaderSimulator, MachineProfile,
                         default_params)
 from repro.core.cache import DPTCache
 from repro.core.cluster import fleet_evaluators, make_fleet
-from repro.core.search import (coordinate_hillclimb, cost_model_warmstart,
-                               goodput_tune, successive_halving,
-                               tuned_with_warmstart)
 from repro.data.loader import TransferStats
 from repro.data.storage import StorageProfile, cifar10_profile
+from repro.tuning import cost_model_warmstart, tune
 
 
 class TableEvaluator:
@@ -123,7 +121,7 @@ CFG = DPTConfig(num_cpu_cores=12, num_devices=1, max_prefetch=8,
 
 def test_successive_halving_matches_grid(sim_ev):
     grid = DPT(sim_ev, CFG).run(measure_default=False)
-    sh = successive_halving(sim_ev, config=CFG)
+    sh = tune(evaluator=sim_ev, strategy="successive_halving", config=CFG)
     assert sh.optimal_time <= grid.optimal_time * 1.05
 
 
@@ -131,16 +129,17 @@ def test_warmstart_hillclimb_matches_grid_with_fewer_calls(sim_ev):
     grid = DPT(sim_ev, CFG).run(measure_default=False)
     ev2 = SimulatorEvaluator(LoaderSimulator(cifar10_profile(),
                                              MachineProfile()), batch_size=32)
-    hc = tuned_with_warmstart(ev2, cifar10_profile(), MachineProfile(),
-                              batch_size=32, config=CFG)
+    hc = tune(evaluator=ev2, strategy="warmstart_hillclimb", config=CFG,
+              storage=cifar10_profile(), machine=MachineProfile(),
+              batch_size=32)
     assert hc.optimal_time <= grid.optimal_time * 1.02
     assert ev2.calls < len(grid.trials) / 4      # >=4x fewer measurements
 
 
 def test_goodput_uses_fewer_workers_when_model_is_slow(sim_ev):
     fast = DPT(sim_ev, CFG).run(measure_default=False)
-    slow_model = goodput_tune(sim_ev, step_time_s=1.0, num_batches=64,
-                              config=CFG)
+    slow_model = tune(evaluator=sim_ev, strategy="goodput", config=CFG,
+                      step_time_s=1.0, num_batches=64)
     assert slow_model.nworker <= fast.nworker
 
 
@@ -225,11 +224,13 @@ def test_cache_reuses_similar_datasets(tmp_path):
     fp_different = dataset_fingerprint(item_bytes=4_000_000, decode_cost=1e-8,
                                        num_items=50_000)
     cache.put("machine", fp_a, 32, res)
-    assert cache.get("machine", fp_similar, 32) == (res.nworker, res.nprefetch)
-    assert cache.get("machine", fp_different, 32) is None
+    assert cache.get_params("machine", fp_similar, 32) == \
+        (res.nworker, res.nprefetch, {})
+    assert cache.get_params("machine", fp_different, 32) is None
     # persisted
     cache2 = DPTCache(str(tmp_path / "dpt.json"))
-    assert cache2.get("machine", fp_a, 32) == (res.nworker, res.nprefetch)
+    assert cache2.get_params("machine", fp_a, 32) == \
+        (res.nworker, res.nprefetch, {})
 
 
 def test_machine_fingerprint_separates_device_kinds():

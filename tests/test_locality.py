@@ -161,7 +161,7 @@ def test_grid_without_locality_axis_never_passes_kwarg():
                config=DPTConfig(num_cpu_cores=2, num_devices=1,
                                 max_prefetch=2, num_batches=4),
                measure_default=False)
-    assert calls and res.locality_chunk == 0
+    assert calls and "locality_chunk" not in res.axes
 
 
 def test_grid_selects_chunked_on_cold_cache_real_loader():
@@ -170,11 +170,11 @@ def test_grid_selects_chunked_on_cold_cache_real_loader():
                     shuffle=True, seed=0)
     cfg = DPTConfig(num_cpu_cores=2, num_devices=2, min_prefetch=1,
                     max_prefetch=1, num_batches=6, epoch=0,
-                    locality_chunks=(0, 32))
+                    axes={"locality_chunk": (0, 32)})
     res = tune(evaluator=LoaderEvaluator(dl, to_device=False),
                strategy="grid", config=cfg, measure_default=False)
-    assert res.locality_chunk == 32
-    assert {t.locality_chunk for t in res.trials} == {0, 32}
+    assert res.axes["locality_chunk"] == 32
+    assert {t.axes["locality_chunk"] for t in res.trials} == {0, 32}
     # measurement-only override: the live schedule never saw the sweep
     assert dl.sampler.chunk_for_epoch(0) == 0
 
@@ -183,10 +183,10 @@ def test_grid_selects_chunked_on_cold_cache_simulator():
     sim = LoaderSimulator(coco_profile(80), MachineProfile())
     ev = SimulatorEvaluator(sim, batch_size=64)
     cfg = DPTConfig(num_cpu_cores=4, num_devices=2, max_prefetch=2,
-                    num_batches=8, epoch=0, locality_chunks=(0, 64))
+                    num_batches=8, epoch=0, axes={"locality_chunk": (0, 64)})
     res = tune(evaluator=ev, strategy="grid", config=cfg,
                measure_default=False)
-    assert res.locality_chunk == 64
+    assert res.axes["locality_chunk"] == 64
 
 
 def test_simulator_locality_neutral_default_and_cold_win():
@@ -201,17 +201,28 @@ def test_simulator_locality_neutral_default_and_cold_win():
 def test_dpt_cache_roundtrips_locality(tmp_path):
     path = str(tmp_path / "cache.json")
     cache = DPTCache(path)
-    res = DPTResult(4, 2, 1.0, [Trial(4, 2, 1.0, locality_chunk=64)],
-                    locality_chunk=64)
+    res = DPTResult(4, 2, 1.0,
+                    [Trial(4, 2, 1.0, axes={"locality_chunk": 64})],
+                    axes={"locality_chunk": 64})
     cache.put("m", "d", 32, res)
-    assert cache.get("m", "d", 32) == (4, 2)        # legacy shape intact
-    assert cache.get_params("m", "d", 32) == (4, 2, 64)
+    assert cache.get_params("m", "d", 32) == (4, 2, {})
     assert DPTCache(path).get_params(
-        "m", "d", 32, require_locality=True) == (4, 2, 64)
+        "m", "d", 32, axes=("locality_chunk",)) == (4, 2,
+                                                    {"locality_chunk": 64})
+    # a file the previous on-disk format wrote (a pre-registry entry with
+    # its geometry keys) reads back the same
+    with open(path, "w") as f:
+        f.write('{"m|d|b5|cold": {"nworker": 4, "nprefetch": 2, '
+                '"optimal_time": 1.0, "locality_chunk": 64, '
+                '"locality_searched": true, "global_batch": 0, '
+                '"geometry_searched": false}}')
+    assert DPTCache(path).get_params(
+        "m", "d", 32, axes=("locality_chunk",)) == (4, 2,
+                                                    {"locality_chunk": 64})
     # an entry from a two-axis sweep must not satisfy a three-axis run
     cache.put("m", "d2", 32, DPTResult(4, 2, 1.0, [Trial(4, 2, 1.0)]))
-    assert cache.get_params("m", "d2", 32) == (4, 2, 0)
-    assert cache.get_params("m", "d2", 32, require_locality=True) is None
+    assert cache.get_params("m", "d2", 32) == (4, 2, {})
+    assert cache.get_params("m", "d2", 32, axes=("locality_chunk",)) is None
 
 
 def test_trainer_locality_axis_ignored_on_sharded_fleet():
@@ -222,7 +233,7 @@ def test_trainer_locality_axis_ignored_on_sharded_fleet():
     dl = DataLoader(ds, 8, params=LoaderParams(), shuffle=True, seed=0,
                     host_index=0, host_count=2)
     cfg = TrainerConfig(autotune=True,
-                        autotune_locality_chunks=(0, 16),
+                        autotune_axes={"locality_chunk": (0, 16)},
                         autotune_budget_batches=2, autotune_max_prefetch=1)
     tr = Trainer.__new__(Trainer)          # tune_loader only needs these
     tr.loader, tr.cfg = dl, cfg
@@ -248,7 +259,7 @@ def test_online_retune_converges_to_grid_optimal_chunk_real_loader():
 
     cfg = OnlineTunerConfig(num_cpu_cores=2, num_devices=2, max_prefetch=1,
                             retune_budget_batches=4,
-                            locality_chunks=(0, 32))
+                            axes={"locality_chunk": (0, 32)})
     tuner = OnlineTuner(dl, evaluator=LoaderEvaluator(dl, to_device=False),
                         config=cfg, machine_fp="m", dataset_fp="d")
     params = tuner.force_retune()
@@ -262,9 +273,9 @@ def test_online_retune_converges_to_grid_optimal_chunk_real_loader():
                 strategy="grid",
                 config=DPTConfig(num_cpu_cores=2, num_devices=2,
                                  max_prefetch=1, num_batches=4,
-                                 locality_chunks=(0, 32)),
+                                 axes={"locality_chunk": (0, 32)}),
                 measure_default=False)
-    assert grid.locality_chunk == params.locality_chunk
+    assert grid.axes["locality_chunk"] == params.locality_chunk
 
     # the stream was never rebuilt: the swap latches mid-flight, epoch 0
     # keeps its order and the chunk engages at the next epoch boundary
@@ -291,7 +302,7 @@ def test_online_retune_converges_to_grid_optimal_chunk_simulator():
                     shuffle=True, seed=0)
     cfg = OnlineTunerConfig(num_cpu_cores=4, num_devices=2, max_prefetch=2,
                             retune_budget_batches=8, strategy="grid",
-                            locality_chunks=(0, 64))
+                            axes={"locality_chunk": (0, 64)})
     tuner = OnlineTuner(dl, evaluator=SimulatorEvaluator(sim, batch_size=64),
                         config=cfg, machine_fp="m", dataset_fp="d")
     params = tuner.force_retune()
@@ -301,9 +312,9 @@ def test_online_retune_converges_to_grid_optimal_chunk_simulator():
                 strategy="grid",
                 config=DPTConfig(num_cpu_cores=4, num_devices=2,
                                  max_prefetch=2, num_batches=8,
-                                 locality_chunks=(0, 64)),
+                                 axes={"locality_chunk": (0, 64)}),
                 measure_default=False)
-    assert grid.locality_chunk == 64 == params.locality_chunk
+    assert grid.axes["locality_chunk"] == 64 == params.locality_chunk
 
 
 def test_online_retune_keeps_good_chunk():
@@ -317,7 +328,7 @@ def test_online_retune_keeps_good_chunk():
                     shuffle=True, seed=0)
     cfg = OnlineTunerConfig(num_cpu_cores=2, num_devices=2, max_prefetch=1,
                             retune_budget_batches=4,
-                            locality_chunks=(0, 32))
+                            axes={"locality_chunk": (0, 32)})
     tuner = OnlineTuner(dl, evaluator=LoaderEvaluator(dl, to_device=False),
                         config=cfg, machine_fp="m", dataset_fp="d")
     assert tuner.force_retune() is None
@@ -410,7 +421,7 @@ def test_fleet_locality_reconsensus_uniform_push(fleet_factory):
         config=FleetConfig(heartbeat_timeout_s=5.0, warmup_steps=2,
                            cooldown_steps=4, num_cpu_cores=4, num_devices=1,
                            max_prefetch=2, retune_budget_batches=2,
-                           locality_chunks=(0, 8)))
+                           axes={"locality_chunk": (0, 8)}))
     for a in fleet.agents:
         from conftest import make_table_evaluator
         a.evaluator = make_table_evaluator(fn, locality=True)
@@ -465,7 +476,7 @@ def test_coordinator_drops_locality_request_without_axis(fleet_factory):
     """An adaptive proposal on a fleet with no locality axis must NOT
     force a re-consensus — the search could never touch the knob, so the
     repeated proposals would burn goodput forever."""
-    fleet = fleet_factory()                     # locality_chunks unset
+    fleet = fleet_factory()                     # no locality axis
     fleet.agents[0].notify_locality(4)
     assert fleet.coord.poll() == []             # nothing forced
     # with the axis configured the same signal IS honoured
@@ -475,7 +486,7 @@ def test_coordinator_drops_locality_request_without_axis(fleet_factory):
         config=FleetConfig(heartbeat_timeout_s=5.0, warmup_steps=2,
                            cooldown_steps=4, num_cpu_cores=4, num_devices=1,
                            max_prefetch=2, retune_budget_batches=2,
-                           locality_chunks=(0, 8)))
+                           axes={"locality_chunk": (0, 8)}))
     for a in fleet2.agents:
         a.evaluator = make_table_evaluator(lambda i, j, c: 1.0,
                                            locality=True)
@@ -541,7 +552,7 @@ def test_fleet_locality_keeps_chunk_when_flat(fleet_factory):
         config=FleetConfig(heartbeat_timeout_s=5.0, warmup_steps=2,
                            cooldown_steps=4, num_cpu_cores=4, num_devices=1,
                            max_prefetch=2, retune_budget_batches=2,
-                           locality_chunks=(0, 8)))
+                           axes={"locality_chunk": (0, 8)}))
     for a in fleet.agents:
         a.evaluator = make_table_evaluator(lambda i, j, c: 1.0,
                                            locality=True)

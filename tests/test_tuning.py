@@ -9,8 +9,6 @@ from repro.core import (DPT, DPTConfig, LoaderSimulator, MachineProfile,
                         MemoryOverflow, SimulatorEvaluator)
 from repro.core.cache import DPTCache
 from repro.core.cluster import degraded_storage
-from repro.core.search import (goodput_tune, successive_halving,
-                               tuned_with_warmstart)
 from repro.data import DataLoader, Dataset, LoaderParams
 from repro.data.loader import TransferStats
 from repro.data.storage import ArrayStorage, cifar10_profile, coco_profile
@@ -67,8 +65,8 @@ def _table(fn, overflow=None):
 
 
 # --------------------------------------------------------------------------
-# parity: the front door returns what the legacy entry points return on the
-# simulator profiles used across tests/test_dpt.py (acceptance criterion)
+# parity: the front door returns what Algorithm 1 and the pinned strategy
+# picks give on the simulator profiles used across tests/test_dpt.py
 # --------------------------------------------------------------------------
 CFG = DPTConfig(num_cpu_cores=12, num_devices=1, max_prefetch=8,
                 num_batches=64)
@@ -89,29 +87,22 @@ def test_front_door_grid_matches_dpt_run():
     assert len(a.trials) == len(b.trials)
 
 
-def test_front_door_successive_halving_matches_legacy():
-    a = tune(evaluator=_sim_ev(), strategy="successive_halving", config=CFG)
-    b = successive_halving(_sim_ev(), config=CFG)
-    assert (a.nworker, a.nprefetch, a.optimal_time) == \
-        (b.nworker, b.nprefetch, b.optimal_time)
-
-
-def test_front_door_warmstart_matches_legacy():
-    a = tune(evaluator=_sim_ev(), strategy="warmstart_hillclimb", config=CFG,
-             storage=cifar10_profile(), machine=MachineProfile(),
-             batch_size=32)
-    b = tuned_with_warmstart(_sim_ev(), cifar10_profile(), MachineProfile(),
-                             batch_size=32, config=CFG)
-    assert (a.nworker, a.nprefetch, a.optimal_time) == \
-        (b.nworker, b.nprefetch, b.optimal_time)
-
-
-def test_front_door_goodput_matches_legacy():
-    a = tune(evaluator=_sim_ev(), strategy="goodput", config=CFG,
-             step_time_s=1.0, num_batches=64)
-    b = goodput_tune(_sim_ev(), step_time_s=1.0, num_batches=64, config=CFG)
-    assert (a.nworker, a.nprefetch, a.optimal_time) == \
-        (b.nworker, b.nprefetch, b.optimal_time)
+@pytest.mark.parametrize("strategy,kwargs,pick", [
+    ("successive_halving", {}, (10, 5, 0.8755469714746349)),
+    ("warmstart_hillclimb",
+     {"storage": cifar10_profile(), "machine": MachineProfile(),
+      "batch_size": 32},
+     (10, 5, 0.8755469714746349)),
+    ("goodput", {"step_time_s": 1.0, "num_batches": 64},
+     (1, 1, 4.778619949578188)),
+])
+def test_front_door_strategy_pick_is_pinned(strategy, kwargs, pick):
+    """Each beyond-paper strategy's pick through ``tune(strategy=...)``
+    on the simulator, pinned to the values the search gave before its
+    per-strategy entry points were retired."""
+    a = tune(evaluator=_sim_ev(), strategy=strategy, config=CFG, **kwargs)
+    assert (a.nworker, a.nprefetch) == pick[:2]
+    assert a.optimal_time == pytest.approx(pick[2], rel=1e-12)
 
 
 def test_overflow_recorded_as_inf_trial():
@@ -246,7 +237,7 @@ def test_online_tuner_retunes_on_goodput_drift(tmp_path):
     assert applied is not None
     assert tuner.retunes == 1
     assert dl.params.num_workers == 4               # hillclimbed to the edge
-    assert cache.get("m", "d", dl.global_batch) == (4, 1)
+    assert cache.get_params("m", "d", dl.global_batch) == (4, 1, {})
 
 
 def test_online_tuner_respects_cooldown():
